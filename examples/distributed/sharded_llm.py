@@ -8,7 +8,6 @@ XLA_FLAGS=--xla_force_host_platform_device_count=8):
 """
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 import traceml_tpu
@@ -25,14 +24,17 @@ print("mesh:", dict(zip(mesh.axis_names, mesh.devices.shape)))
 cfg = ModelConfig(vocab_size=8192, hidden=512, n_layers=4, n_heads=8,
                   n_kv_heads=4, max_seq_len=512)
 model, state, tx = init_train_state(cfg, jax.random.PRNGKey(0), mesh=mesh)
-step = traceml_tpu.wrap_step_fn(make_train_step(model, tx), donate_argnums=(0,))
+step = traceml_tpu.wrap_step_fn(
+    make_train_step(model, tx, mesh=mesh), donate_argnums=(0,)
+)
 
 ici = IciStatAggregator(mesh)
 rng = np.random.default_rng(0)
 for i in range(30):
     with traceml_tpu.trace_step() as ts:
+        # host array → each device receives only its batch shard
         tokens = jax.device_put(
-            jnp.asarray(rng.integers(0, cfg.vocab_size, (8, 256)), jnp.int32),
+            rng.integers(0, cfg.vocab_size, (8, 256)).astype(np.int32),
             batch_sharding(mesh),
         )
         state, metrics = step(state, tokens)

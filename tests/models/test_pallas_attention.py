@@ -85,3 +85,54 @@ def test_flash_uneven_block_pair():
     out = flash_attention(q, k, v, blk_q=128, blk_k=64)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
+
+
+def test_flash_grad_matches_reference():
+    """The kernel's VJP is the reference path's, so a train step can
+    differentiate through it."""
+    q, k, v = _qkv(B=1, S=256, H=2, D=64)
+
+    def loss(attn):
+        return lambda q, k, v: (attn(q, k, v) ** 2).sum()
+
+    got = jax.grad(loss(flash_attention), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss(causal_attention_reference), argnums=(0, 1, 2))(
+        q, k, v
+    )
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   atol=1e-4, rtol=1e-4)
+
+
+@pytest.mark.parametrize(
+    "S, kv_S, expect_flash",
+    [(1024, 1024, True), (1023, 1023, False), (1024 + 64, 1024 + 64, False),
+     (1024, 2048, False)],
+)
+def test_flash_eligible_conditions(S, kv_S, expect_flash):
+    from traceml_tpu.ops.attention import flash_eligible
+
+    q = jax.ShapeDtypeStruct((1, S, 2, 64), jnp.float32)
+    kv = jax.ShapeDtypeStruct((1, kv_S, 2, 64), jnp.float32)
+    assert flash_eligible(q, kv, kv) is expect_flash
+
+
+def test_dispatcher_propagates_kernel_errors(monkeypatch):
+    """A kernel that fails to lower raises; no silent reference path."""
+
+    def broken(q, k, v):
+        raise RuntimeError("mosaic lowering failed")
+
+    monkeypatch.setattr(
+        "traceml_tpu.ops.pallas_attention.flash_attention", broken
+    )
+    q, k, v = _qkv(B=1, S=1024, H=1, D=64)
+    with pytest.raises(RuntimeError, match="mosaic"):
+        causal_attention(q, k, v)
+
+
+def test_flash_refuses_unknown_platform(monkeypatch):
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    q, k, v = _qkv(B=1, S=128, H=1, D=64)
+    with pytest.raises(NotImplementedError, match="gpu"):
+        flash_attention(q, k, v)

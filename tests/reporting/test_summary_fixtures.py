@@ -154,7 +154,7 @@ def test_collective_phase_in_summary(tmp_path):
 
 
 def test_memory_without_limits(tmp_path):
-    """CPU/tunneled runtimes have no bytes_limit — pressure is None, no
+    """CPU/remote runtimes have no bytes_limit — pressure is None, no
     pressure verdicts, schema intact."""
     s = _Session(tmp_path)
     mem = [{"step": i, "timestamp": float(i), "device_id": 0,

@@ -1,7 +1,7 @@
 """Overhead-governor policy tests.
 
 The governor is the TPU answer to the reference's fixed "<1% overhead"
-claim: observation cost is runtime-dependent (local probe ≈ µs, tunneled
+claim: observation cost is runtime-dependent (local probe ≈ µs, remote
 PJRT probe ≈ RPC), so the sampling schedule must adapt.  These tests pin
 the policy: cheap probes + realistic steps → full sampling; expensive
 probes or tiny steps → stride growth, inline sweeps off, resolver

@@ -5,7 +5,7 @@ utilization gauge driven by the SAME system payload).
 The gauge is an SVG progress ring over the best available busy signal:
 libtpu duty-cycle when chips report it, else the step-time view's
 median occupancy (device-busy share of wall) — labeled with which
-source is showing, so a tunneled chip that can't answer duty-cycle
+source is showing, so a remote chip that can't answer duty-cycle
 still gets an honest dial.  The CPU history chart carries a crosshair
 tooltip like the step chart.
 """

@@ -16,7 +16,7 @@ manifest can carry the probe evidence instead of a bare unknown
 3. PJRT client attributes (``platform_version``, device attributes) —
    identifies the client so absence is attributable;
 4. ``/dev/accel*`` + ``/sys/class/accel`` — present only when the chip
-   is local (not tunneled), where vfio counters could be read.
+   is local (not remote), where vfio counters could be read.
 
 Usage::
 

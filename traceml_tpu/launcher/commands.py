@@ -47,6 +47,14 @@ ENV_AGG_MAX_RESTARTS = flags.AGG_MAX_RESTARTS.name
 DEFAULT_AGG_MAX_RESTARTS = 3
 
 
+def _aggregator_env(base_env: Dict[str, str]) -> Dict[str, str]:
+    """The aggregator never needs an accelerator: pin any JAX it might
+    import to the CPU, so it cannot take a chip from a rank."""
+    env = dict(base_env)
+    env["JAX_PLATFORMS"] = "cpu"
+    return env
+
+
 def _restart_aggregator(
     session_dir: Path, base_env: Dict[str, str], port: int
 ) -> Optional[SupervisedChild]:
@@ -63,7 +71,7 @@ def _restart_aggregator(
         ready_path.unlink()
     except OSError:
         pass
-    env = dict(base_env)
+    env = _aggregator_env(base_env)
     env[ENV_AGG_PORT] = str(port)
     # A fault plan's counters are per-process: the restarted aggregator
     # would re-parse the inherited plan with fresh counters and a kill9
@@ -238,7 +246,7 @@ def launch_process(
         agg_child = spawn_supervised(
             python_argv("traceml_tpu.aggregator.aggregator_main"),
             label="aggregator",
-            env=base_env,
+            env=_aggregator_env(base_env),
         )
         ready = wait_for_ready_file(
             session_dir / "aggregator_ready.json", timeout=30.0

@@ -25,8 +25,6 @@ import jax
 import jax.numpy as jnp
 import flax.linen as nn
 
-from traceml_tpu.utils.jax_compat import shard_map
-
 
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
@@ -65,6 +63,13 @@ class ModelConfig:
     def tiny(cls) -> "ModelConfig":
         return cls(vocab_size=256, hidden=128, n_layers=2, n_heads=4,
                    n_kv_heads=2, max_seq_len=128)
+
+    @classmethod
+    def flagship(cls) -> "ModelConfig":
+        """The one-chip TPU width of bench.py and chip_smoke.py: ~175M
+        params, head_dim 64; trained at batch 16 x 512 tokens."""
+        return cls(vocab_size=16384, hidden=1024, n_layers=12, n_heads=16,
+                   n_kv_heads=8, max_seq_len=512)
 
 
 class RMSNorm(nn.Module):
@@ -221,7 +226,7 @@ class Attention(nn.Module):
                 "single-mesh GSPMD partitioning"
             )
         spec = seq_parallel_spec(cfg, batch_size=q.shape[0])
-        return shard_map(
+        return jax.shard_map(
             lambda a, b, c: op(a, b, c, cfg.context_axis),
             mesh=cfg.mesh,
             in_specs=(spec, spec, spec),
@@ -345,30 +350,42 @@ def init_train_state(
 ) -> Tuple[Any, Dict[str, Any], Any]:
     """Returns (model, state, optimizer).  state = {params, opt_state, step}.
 
-    With a mesh, params and optimizer state are sharded per
-    `param_shardings` (jax.device_put applies GSPMD layouts directly).
+    With a mesh, params are initialised directly in their
+    `param_shardings` layout (each device builds only its shard), and
+    the optimizer state inherits it.
     """
     import optax
 
     model = DecoderLM(cfg)
     rng = rng if rng is not None else jax.random.PRNGKey(0)
     tokens = jnp.zeros((2, min(16, cfg.max_seq_len)), dtype=jnp.int32)
-    params = model.init(rng, tokens)["params"]
-    tx = optax.adamw(learning_rate, weight_decay=0.01)
+
+    def init(key):
+        return model.init(key, tokens)["params"]
+
+    out_shardings = None
     if mesh is not None:
-        shardings = param_shardings(params, mesh)
-        params = jax.device_put(params, shardings)
+        out_shardings = param_shardings(jax.eval_shape(init, rng), mesh)
+    params = jax.jit(init, out_shardings=out_shardings)(rng)
+    tx = optax.adamw(learning_rate, weight_decay=0.01)
     opt_state = tx.init(params)
     state = {"params": params, "opt_state": opt_state, "step": jnp.zeros((), jnp.int32)}
+    if mesh is not None:
+        # every leaf in the layout make_train_step(mesh=mesh) returns it
+        # in, so the step's output state re-enters without a recompile
+        state = jax.device_put(state, param_shardings(state, mesh))
     return model, state, tx
 
 
-def make_train_step(model: DecoderLM, tx) -> Any:
+def make_train_step(model: DecoderLM, tx, mesh=None) -> Any:
     """The (un-jitted) functional train step: (state, tokens) → (state, metrics).
 
     Callers wrap it with ``traceml_tpu.wrap_step_fn`` (tracing + AOT
     compile attribution) or plain ``jax.jit``; donate state for in-place
-    updates.
+    updates.  With the ``mesh`` the state was initialised on, the new
+    state is held to ``param_shardings``, the layout it came in with:
+    left to itself the compiler may lay out optimizer leaves otherwise,
+    and the second step would compile again.
     """
     import optax
 
@@ -385,6 +402,10 @@ def make_train_step(model: DecoderLM, tx) -> Any:
             "opt_state": opt_state,
             "step": state["step"] + 1,
         }
+        if mesh is not None:
+            new_state = jax.lax.with_sharding_constraint(
+                new_state, param_shardings(new_state, mesh)
+            )
         return new_state, {"loss": loss}
 
     return train_step

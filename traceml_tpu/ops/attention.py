@@ -3,8 +3,9 @@
 The reference path is a single einsum-softmax-einsum chain that XLA
 fuses and MXU-tiles well at the model sizes the demos/bench use.  The
 pallas flash-attention kernel (ops/pallas_attention.py) takes over for
-long sequences where the S×S score matrix would blow HBM; selection is
-automatic and fail-open.
+long sequences where the S×S score matrix would blow HBM, wherever its
+shape conditions (:func:`flash_eligible`) hold.  A kernel that fails to
+lower or compile raises: it never falls back in silence.
 """
 
 from __future__ import annotations
@@ -36,13 +37,23 @@ def causal_attention_reference(
     return attention_reference(q, k, v, causal=True)
 
 
-def causal_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray) -> jnp.ndarray:
-    S = q.shape[1]
-    if S >= _PALLAS_MIN_SEQ:
-        try:
-            from traceml_tpu.ops.pallas_attention import flash_attention
+def flash_eligible(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray) -> bool:
+    """The flash kernel's conditions: self-attention (q, k and v share
+    one shape), S ≥ ``_PALLAS_MIN_SEQ`` and S a multiple of the kernel's
+    block size."""
+    from traceml_tpu.ops.pallas_attention import BLOCK
 
-            return flash_attention(q, k, v)
-        except Exception:
-            pass  # fail open to the reference path
+    S = q.shape[1]
+    return (
+        q.shape == k.shape == v.shape
+        and S >= _PALLAS_MIN_SEQ
+        and S % BLOCK == 0
+    )
+
+
+def causal_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray) -> jnp.ndarray:
+    if flash_eligible(q, k, v):
+        from traceml_tpu.ops.pallas_attention import flash_attention
+
+        return flash_attention(q, k, v)
     return causal_attention_reference(q, k, v)

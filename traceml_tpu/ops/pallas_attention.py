@@ -17,8 +17,12 @@ Kernel layout (one program per (batch*head, q-block)):
 * online softmax in f32 (m, l, acc carries), one write of the output
   block at the end.
 
-On non-TPU backends the kernel runs in interpret mode (CI numerics);
-``ops.attention.causal_attention`` handles selection and fail-open.
+The kernel is compiled by Mosaic on TPU and run by the Pallas
+interpreter on CPU (CI numerics); any other backend is an error.  The
+backward pass is the reference path's VJP, recomputed from q, k and v,
+so the kernel can sit inside a differentiated train step.
+``ops.attention.causal_attention`` selects it where its shape
+conditions hold.
 """
 
 from __future__ import annotations
@@ -30,6 +34,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 _NEG_INF = -1e30
+#: default q and k block rows; S must be a multiple of the block
+BLOCK = 128
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, blk_q: int, blk_k: int, scale: float):
@@ -91,27 +97,59 @@ def _flash_bhsd(q, k, v, blk_q: int, blk_k: int, interpret: bool):
     )(q, k, v)
 
 
-def flash_attention(
-    q: jnp.ndarray,
-    k: jnp.ndarray,
-    v: jnp.ndarray,
-    blk_q: int = 128,
-    blk_k: int = 128,
-) -> jnp.ndarray:
-    """Causal flash attention; q,k,v: (B, S, H, D) → (B, S, H, D).
+def _interpret() -> bool:
+    """Mosaic on TPU, the Pallas interpreter on CPU; nothing else."""
+    platform = jax.default_backend()
+    if platform not in ("tpu", "cpu"):
+        raise NotImplementedError(
+            f"flash_attention has no kernel for platform {platform!r}"
+        )
+    return platform == "cpu"
 
-    Constraints (caller falls back to the reference path otherwise):
-    S divisible by the block sizes; same S for q and k/v (self-attention).
-    """
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _flash(q, k, v, blk_q: int, blk_k: int):
     B, S, H, D = q.shape
-    blk_q = min(blk_q, S)
-    blk_k = min(blk_k, S)
-    if S % blk_q or S % blk_k:
-        raise ValueError(f"S={S} not divisible by blocks ({blk_q},{blk_k})")
-    interpret = jax.default_backend() != "tpu"
 
     def to_bhsd(x):
         return x.transpose(0, 2, 1, 3).reshape(B * H, S, D)
 
-    out = _flash_bhsd(to_bhsd(q), to_bhsd(k), to_bhsd(v), blk_q, blk_k, interpret)
+    out = _flash_bhsd(
+        to_bhsd(q), to_bhsd(k), to_bhsd(v), blk_q, blk_k, _interpret()
+    )
     return out.reshape(B, H, S, D).transpose(0, 2, 1, 3)
+
+
+def _flash_fwd(q, k, v, blk_q, blk_k):
+    return _flash(q, k, v, blk_q, blk_k), (q, k, v)
+
+
+def _flash_bwd(blk_q, blk_k, residuals, g):
+    from traceml_tpu.ops.attention import causal_attention_reference
+
+    _, vjp = jax.vjp(causal_attention_reference, *residuals)
+    return vjp(g)
+
+
+_flash.defvjp(_flash_fwd, _flash_bwd)
+
+
+def flash_attention(
+    q: jnp.ndarray,
+    k: jnp.ndarray,
+    v: jnp.ndarray,
+    blk_q: int = BLOCK,
+    blk_k: int = BLOCK,
+) -> jnp.ndarray:
+    """Causal flash attention; q,k,v: (B, S, H, D) → (B, S, H, D).
+
+    Constraints (``ops.attention.flash_eligible`` checks them before
+    selecting this kernel): S divisible by the block sizes; same S for
+    q and k/v (self-attention).
+    """
+    S = q.shape[1]
+    blk_q = min(blk_q, S)
+    blk_k = min(blk_k, S)
+    if S % blk_q or S % blk_k:
+        raise ValueError(f"S={S} not divisible by blocks ({blk_q},{blk_k})")
+    return _flash(q, k, v, blk_q, blk_k)

@@ -27,8 +27,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from traceml_tpu.utils import jax_compat
-from traceml_tpu.utils.jax_compat import shard_map
 
 _NEG = -1e30
 
@@ -54,7 +52,7 @@ def ring_attention(
     axis_name: str,
 ) -> jnp.ndarray:
     """Causal ring attention over ``axis_name``; q,k,v: local (B,S,H,D)."""
-    axis_size = jax_compat.axis_size(axis_name)
+    axis_size = jax.lax.axis_size(axis_name)
     my_idx = jax.lax.axis_index(axis_name)
     B, S_loc, H, D = q.shape
     scale = 1.0 / jnp.sqrt(jnp.asarray(D, jnp.float32))
@@ -111,7 +109,7 @@ def make_ring_attention(mesh, axis_name: str = "context"):
         return ring_attention(q, k, v, axis_name)
 
     return jax.jit(
-        shard_map(
+        jax.shard_map(
             fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
             check_vma=False,
         )

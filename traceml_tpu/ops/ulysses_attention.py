@@ -36,9 +36,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from traceml_tpu.utils import jax_compat
-from traceml_tpu.utils.jax_compat import shard_map
-
 
 def _full_causal_attention(q, k, v):
     """Ordinary causal attention on full-sequence local tensors.
@@ -65,7 +62,7 @@ def ulysses_attention(
     q,k,v: local (B, S_local, H, D); H must be divisible by the axis
     size.  Returns the local (B, S_local, H, D) output shard.
     """
-    P = jax_compat.axis_size(axis_name)
+    P = jax.lax.axis_size(axis_name)
     B, S_loc, H, D = q.shape
     if H % P != 0:
         raise ValueError(
@@ -109,7 +106,7 @@ def make_ulysses_attention(mesh, axis_name: str = "context"):
         return ulysses_attention(q, k, v, axis_name)
 
     return jax.jit(
-        shard_map(
+        jax.shard_map(
             fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
             check_vma=False,
         )

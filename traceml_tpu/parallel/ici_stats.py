@@ -75,8 +75,6 @@ class IciStatAggregator:
         import jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
 
-        from traceml_tpu.utils.jax_compat import shard_map
-
         axes = self.axes
 
         def gather(local: jnp.ndarray) -> jnp.ndarray:
@@ -95,7 +93,7 @@ class IciStatAggregator:
         # (all_gather makes it so), but static replication inference
         # can't always prove it across multiple chained axes.
         return jax.jit(
-            shard_map(
+            jax.shard_map(
                 gather,
                 mesh=self.mesh,
                 in_specs=P(axes),
@@ -119,37 +117,31 @@ class IciStatAggregator:
         in tests and single-host jobs): the same vector is contributed
         for every local device shard.
         """
-        import jax
-        import jax.numpy as jnp
-        from jax.sharding import NamedSharding, PartitionSpec as P
-
         n = self.n_participants
-        local = jnp.broadcast_to(
-            jnp.asarray(stats.to_array())[None, :], (n, N_FIELDS)
+        return self._place_and_gather(
+            np.broadcast_to(stats.to_array()[None, :], (n, N_FIELDS))
         )
-        sharding = NamedSharding(self.mesh, P(self.axes))
-        local = jax.device_put(local, sharding)
-        with self.mesh:
-            out = self._gather(local)
-        return np.asarray(jax.device_get(out))
 
     def aggregate_many(self, stats: Sequence[StatVector]) -> np.ndarray:
         """Single-controller variant: place DISTINCT per-device vectors
         (len must equal n_participants) and gather.  Tests and
         single-host jobs use this to exercise the real collective with
         heterogeneous per-chip stats."""
-        import jax
-        import jax.numpy as jnp
-        from jax.sharding import NamedSharding, PartitionSpec as P
-
         n = self.n_participants
         if len(stats) != n:
             raise ValueError(f"need {n} vectors, got {len(stats)}")
-        local = jnp.asarray(
-            np.stack([s.to_array() for s in stats]), dtype=jnp.float32
+        return self._place_and_gather(np.stack([s.to_array() for s in stats]))
+
+    def _place_and_gather(self, rows: np.ndarray) -> np.ndarray:
+        """Host rows → one row per device (each device receives only
+        its own row) → the gathered matrix on the host."""
+        import jax
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        local = jax.device_put(
+            np.ascontiguousarray(rows, dtype=np.float32),
+            NamedSharding(self.mesh, P(self.axes)),
         )
-        sharding = NamedSharding(self.mesh, P(self.axes))
-        local = jax.device_put(local, sharding)
         with self.mesh:
             out = self._gather(local)
         return np.asarray(jax.device_get(out))

@@ -30,8 +30,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from traceml_tpu.utils.jax_compat import shard_map
-
 
 def stack_stage_params(per_stage_params: list) -> Any:
     """[stage0_tree, stage1_tree, …] → one tree with a leading stage dim."""
@@ -139,7 +137,7 @@ def make_pipeline_fn(
         n_leaf_specs = jax.tree_util.tree_map(
             lambda _: P(axis), stacked_params
         )
-        return shard_map(
+        return jax.shard_map(
             wrapped,
             mesh=mesh,
             in_specs=(n_leaf_specs, P()),

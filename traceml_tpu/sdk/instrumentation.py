@@ -60,7 +60,7 @@ class trace_step:
             gov = get_governor()
             # Stamp the previous step's markers from this thread before
             # opening a new step — see MarkerResolver.sweep_inline.  On
-            # expensive-probe runtimes (tunneled PJRT: is_ready is an
+            # expensive-probe runtimes (remote PJRT: is_ready is an
             # RPC) the governor moves stamping off the critical path to
             # the background resolver instead.
             if gov.allow_inline_sweep():

@@ -52,7 +52,7 @@ def _poll_batch(pending: List[DeviceMarker]) -> tuple:
         dt = time.perf_counter() - t0
         if best is None or dt < best:
             best = dt
-    # this is THE signal that detects RPC-priced is_ready (tunneled
+    # this is THE signal that detects RPC-priced is_ready (remote
     # PJRT) and turns inline sweeping off / stretches the marker stride
     if best is not None:
         get_governor().observe_probe(best, 1)
@@ -232,7 +232,7 @@ class MarkerResolver:
                     delay = self._interval
                 # expensive-probe floor: keep this thread's probe duty
                 # cycle within the overhead budget (RPC-priced is_ready
-                # through a tunneled PJRT client must not hammer the
+                # through a remote PJRT client must not hammer the
                 # channel the main thread dispatches on)
                 delay = max(delay, get_governor().resolver_min_delay())
                 # waiting on _wake (not _stop) lets a fresh submit

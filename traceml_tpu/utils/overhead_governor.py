@@ -3,7 +3,7 @@
 The reference promises "<1% overhead" but enforces it by construction
 (CUDA events are cheap and local).  On TPU the cost model is runtime-
 dependent: a PJRT ``is_ready()`` probe is ~1 µs on a local backend but
-can be a full RPC round-trip (~0.3 ms) through a tunneled/remote PJRT
+can be a full RPC round-trip (~0.3 ms) through a remote PJRT
 client — and a training step can be sub-millisecond when the host loop
 is dispatch-bound.  A fixed per-step observation schedule therefore has
 no fixed cost: the SAME tracer is 0.02% on one runtime and 30% on

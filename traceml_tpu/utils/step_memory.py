@@ -14,7 +14,7 @@ The reference resets ``torch.cuda`` peak stats at step start and reads
 * ``limit_bytes``     — device capacity
 
 Backends are pluggable because ``memory_stats()`` returns ``None`` on
-some runtimes (CPU, tunneled devices): the live-arrays backend sums
+some runtimes (CPU, remote devices): the live-arrays backend sums
 ``jax.live_arrays()`` nbytes per device, and tests inject a deterministic
 fake (SURVEY.md §4 "fake device layer").
 """

@@ -73,7 +73,7 @@ class TpuMetricsReader:
 
     def duty_cycle_by_device(self) -> Optional[List[float]]:
         """Percent busy per local chip over the last sample period, or
-        None when the counter is dark (tunneled client, old libtpu)."""
+        None when the counter is dark (remote client, old libtpu)."""
         return self._metric_values("duty_cycle_pct")
 
     def tensorcore_util_by_device(self) -> Optional[List[float]]:
