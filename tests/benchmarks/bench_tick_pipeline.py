@@ -209,11 +209,9 @@ def _seed_db(db, ranks, steps):
 
 def _payload_bytes(pub):
     """Served payload canonicalized for cross-arm comparison: drop the
-    wall-clock stamp and the profiler block (timings differ by arm by
-    construction — every OTHER byte must match)."""
+    wall-clock stamp — every OTHER byte must match."""
     obj = pub.full_payload_dict()
     obj.pop("ts", None)
-    obj.pop("window_build", None)
     return json.dumps(obj, sort_keys=True).encode()
 
 

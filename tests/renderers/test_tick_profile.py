@@ -8,9 +8,9 @@ Pins the r20 contracts (docs/developer_guide/diagnosis-engine.md):
   per-(domain, version) cache returns the previous DiagnosticResult
   object (``diag_cache_hits`` counts it, ``rule_eval_counts`` proves
   no rule evaluated);
-* the profiler surfaces through ``window_build_stats()`` →
-  ``window_build`` meta → the serving tier, including the per-fragment
-  ``serialize`` stage;
+* the profiler surfaces through ``window_build_stats()`` (and the final
+  report's ``meta.window_build``), including the serving tier's
+  per-fragment ``serialize`` stage — never through the live payload;
 * with ``TRACEML_VECTOR_DIAGNOSIS=0`` the served payload bytes are
   byte-identical to the scalar legacy path (twin-session pin, same
   pattern as the ``TRACEML_INCR_WINDOW=0`` pin in
@@ -282,13 +282,11 @@ def test_serialize_stage_recorded_by_publisher(tmp_path):
 # -- TRACEML_VECTOR_DIAGNOSIS=0 payload byte-pin -------------------------
 
 
-def _payload_bytes(db, drop_stats=True):
+def _payload_bytes(db):
     from traceml_tpu.renderers.web_payload import build_web_payload
 
     payload = build_web_payload(db, "s1")
     payload.pop("ts", None)  # wall-clock
-    if drop_stats:
-        payload.pop("window_build", None)  # timings differ run to run
     return json.dumps(payload, sort_keys=True).encode()
 
 

@@ -45,8 +45,12 @@ class SessionRegistry:
         window_steps: int = 150,
         max_sessions: int = 8,
         fleet_cache_ttl: float = 0.0,
+        freshness: Optional[Any] = None,
     ) -> None:
         self.logs_dir = Path(logs_dir)
+        #: the aggregator's FreshnessTracker, told about the default
+        #: session's step_time publishes (aggregator/freshness.py)
+        self.freshness = freshness
         self.default_session = default_session
         self.window_steps = window_steps
         self.max_sessions = max(1, int(max_sessions))
@@ -126,6 +130,8 @@ class SessionRegistry:
             window_steps=self.window_steps,
             max_publishers=self.max_sessions,
         )
+        if self.freshness is not None and session_id == self.default_session:
+            pub.freshness = self.freshness
         with self._lock:
             self._open[session_id] = pub
         return pub

@@ -46,7 +46,7 @@ import threading
 import time
 from collections import deque
 from pathlib import Path
-from typing import Deque, Dict, List, Optional, Tuple
+from typing import Callable, Deque, Dict, List, Optional, Tuple
 
 from traceml_tpu.aggregator import rollup
 from traceml_tpu.aggregator.sqlite_writers import ALL_WRITERS, writer_for
@@ -123,6 +123,7 @@ class SQLiteWriter:
         group_commit_envelopes: int = _GROUP_COMMIT_ENVS,
         group_commit_interval_s: float = _GROUP_COMMIT_INTERVAL_S,
         prune_partitions_per_slice: int = _PRUNE_PARTITIONS_PER_SLICE,
+        on_commit: Optional[Callable[[List[TelemetryEnvelope]], None]] = None,
     ) -> None:
         self.db_path = Path(db_path)
         self._retention_rows = int(summary_window_rows * retention_factor)
@@ -136,6 +137,9 @@ class SQLiteWriter:
         self._group_envs = int(group_commit_envelopes)
         self._group_interval = float(group_commit_interval_s)
         self._prune_slice_max = int(prune_partitions_per_slice)
+        # called on the writer thread with each batch right after its
+        # transaction commits (the freshness tracker's ingest stage)
+        self._on_commit = on_commit
 
         self._queues: Tuple["queue.Queue[object]", ...] = (
             queue.Queue(maxsize=queue_max_high),
@@ -653,6 +657,11 @@ class SQLiteWriter:
             # in-memory maxima advance only after the commit lands —
             # on rollback the rows are gone, so their replay must pass
             self._seq_max.update(seq_touched)
+            if self._on_commit is not None:
+                try:
+                    self._on_commit(batch)
+                except Exception as exc:
+                    get_error_log().warning("on_commit hook failed", exc)
         except sqlite3.Error as exc:
             get_error_log().warning("sqlite batch write failed", exc)
             try:

@@ -27,6 +27,7 @@ from collections import deque
 from typing import Any, Dict, List, Optional, Set
 
 from traceml_tpu.aggregator.display_drivers import resolve_display_driver
+from traceml_tpu.aggregator.freshness import FreshnessTracker
 from traceml_tpu.aggregator.liveness import RankLivenessTracker
 from traceml_tpu.aggregator.session_registry import SessionRegistry
 from traceml_tpu.aggregator.sqlite_writer import SQLiteWriter
@@ -79,8 +80,13 @@ class TraceMLAggregator:
             except Exception as exc:
                 get_error_log().warning("shm ring registry unavailable", exc)
         self.db_path = settings.session_dir / "telemetry.sqlite"
+        # rank 0's step rows followed from frame arrival to commit to
+        # the first publish that shows them (aggregator/freshness.py)
+        self.freshness = FreshnessTracker()
         self.writer = SQLiteWriter(
-            self.db_path, summary_window_rows=settings.summary_window_rows
+            self.db_path,
+            summary_window_rows=settings.summary_window_rows,
+            on_commit=self.freshness.committed,
         )
         self.display = resolve_display_driver(settings.mode)
         # serving tier: the display driver reads THROUGH this registry,
@@ -90,6 +96,7 @@ class TraceMLAggregator:
             settings.logs_dir,
             default_session=settings.session_id,
             max_sessions=settings.serve_max_sessions,
+            freshness=self.freshness,
         )
         self.summary_service = FinalSummaryService(
             settings,
@@ -243,7 +250,7 @@ class TraceMLAggregator:
         #   3. ingest in ticket order under _ingest_cond, preserving the
         #      seed's strict frame ordering into the writer queues.
         with self._drain_lock:
-            frames = self.server.drain_tagged(max_frames)
+            frames, arrivals = self.server.drain_timed(max_frames)
             ticket = self._drain_ticket
             self._drain_ticket += 1
             cursors = None
@@ -257,18 +264,22 @@ class TraceMLAggregator:
                         self._shm_frames_drained
                     )
         payloads: List[Any] = []
+        payload_arrivals: List[float] = []
         try:
-            if frames:
-                # tagged decode: a corrupt frame is counted against its
-                # peer and skipped instead of poisoning the whole batch
-                payloads = self.server.decode_tagged(frames)
+            # tagged decode, frame by frame: a corrupt frame is counted
+            # against its peer and skipped instead of poisoning the
+            # whole batch, and each payload keeps its frame's arrival
+            for frame, t in zip(frames, arrivals):
+                decoded = self.server.decode_tagged([frame])
+                payloads.extend(decoded)
+                payload_arrivals.extend([t] * len(decoded))
         finally:
             n = 0
             with self._ingest_cond:
                 while ticket != self._ingest_next:
                     self._ingest_cond.wait(1.0)
                 try:
-                    for p in payloads:
+                    for p, t in zip(payloads, payload_arrivals):
                         self._chaos_ingest_hook()
                         if is_control_message(p):
                             self._handle_control(p)
@@ -276,6 +287,8 @@ class TraceMLAggregator:
                         env = normalize_telemetry_envelope(p)
                         if env is None:
                             continue
+                        if self.freshness.follows(env):
+                            self.freshness.arrived(env, t)
                         self._seen_ranks.add(env.global_rank)
                         self.liveness.observe(
                             env.global_rank,
@@ -370,6 +383,7 @@ class TraceMLAggregator:
                     for rank, stats in sorted(self._producer_stats.items())
                 },
                 "transports": self._transport_stats(),
+                "freshness": self.freshness.block(),
                 "final": final,
                 "ts": time.time(),
             },

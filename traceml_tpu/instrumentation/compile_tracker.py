@@ -13,6 +13,9 @@ state) and it observes ALL compilations in the process, including ones
 in code we never wrapped — exactly what a recompile-storm diagnosis
 needs.
 
+Each counted compile also lands in ``TraceState.compile_ring`` as
+(monotonic end time, ms), so the compiles of any window can be counted.
+
 Fail-open: listener errors are swallowed; events fire synchronously on
 the dispatching thread, so the TLS step gate works unchanged.
 """
@@ -20,6 +23,7 @@ the dispatching thread, so the TLS step gate works unchanged.
 from __future__ import annotations
 
 import threading
+import time
 from typing import Optional
 
 from traceml_tpu.sdk.state import TraceState, get_state
@@ -86,6 +90,7 @@ def _listener(event: str, duration: float, **kwargs) -> None:
         }
         st.buffer.add(ev)
         st.compile_events_seen += 1
+        st.compile_ring.add(time.monotonic(), total_s * 1000.0)
     except Exception as exc:  # never raise into jax internals
         try:
             get_error_log().warning("compile listener failed", exc)

@@ -382,21 +382,30 @@ def make_train_step(model: DecoderLM, tx, mesh=None) -> Any:
 
     Callers wrap it with ``traceml_tpu.wrap_step_fn`` (tracing + AOT
     compile attribution) or plain ``jax.jit``; donate state for in-place
-    updates.  With the ``mesh`` the state was initialised on, the new
+    updates.  The loss runs in a ``forward`` name scope, so its
+    operations carry ``forward`` and their gradients
+    ``transpose(jvp(forward))`` in their names; the update runs in
+    ``optimizer``.  Scopes are metadata: the compiled program is the
+    same with or without them.  With the ``mesh`` the state was initialised on, the new
     state is held to ``param_shardings``, the layout it came in with:
     left to itself the compiler may lay out optimizer leaves otherwise,
     and the second step would compile again.
     """
     import optax
 
+    def forward(params, apply, tokens):
+        with jax.named_scope("forward"):
+            return loss_fn(params, apply, tokens)
+
     def train_step(state, tokens):
-        loss, grads = jax.value_and_grad(loss_fn)(
+        loss, grads = jax.value_and_grad(forward)(
             state["params"], model.apply, tokens
         )
-        updates, opt_state = tx.update(
-            grads, state["opt_state"], state["params"]
-        )
-        params = optax.apply_updates(state["params"], updates)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = tx.update(
+                grads, state["opt_state"], state["params"]
+            )
+            params = optax.apply_updates(state["params"], updates)
         new_state = {
             "params": params,
             "opt_state": opt_state,

@@ -28,10 +28,7 @@ from traceml_tpu.diagnostics.common import rule_eval_counts
 from traceml_tpu.diagnostics.step_time.api import diagnose_window
 from traceml_tpu.renderers import views as V
 from traceml_tpu.reporting.snapshot_store import LiveSnapshotStore
-from traceml_tpu.utils.columnar import (
-    incr_window_enabled,
-    vector_diagnosis_enabled,
-)
+from traceml_tpu.utils.columnar import vector_diagnosis_enabled
 
 # payload domain → (store versions it depends on, views key or None)
 # collectives also depends on step_time: COMM_BOUND needs the mean step
@@ -89,9 +86,6 @@ class LiveComputer:
         self._step_ms_memo: Optional[Tuple[Tuple, Optional[float]]] = None
         self._st_tables_memo: Dict[str, Any] = {}
         self._coll_memo: Optional[Tuple[Tuple, Tuple[Dict[str, Any], Any]]] = None
-        # last exported window_build_stats snapshot, keyed on the store
-        # versions it was taken at (see payload_with_versions)
-        self._stats_export: Optional[Tuple[Tuple, Dict[str, Any]]] = None
 
     @property
     def store(self) -> LiveSnapshotStore:
@@ -154,25 +148,6 @@ class LiveComputer:
         fragment cache on these, so the pair must be consistent."""
         with self._lock:
             payload = self.payload()
-            if incr_window_enabled():
-                # the exported stats block is version-gated: the live
-                # profile accumulates on every poll (refresh ns, idle
-                # serializations), but serving a fresh snapshot each
-                # time would churn the meta fragment's bytes forever
-                # and break the idle-tick 204 contract.  Idle polls
-                # re-serve the previous snapshot; any store-version
-                # change exports a fresh one (with the idle time in it)
-                vkey = tuple(sorted(self._store.versions.items()))
-                if (
-                    self._stats_export is not None
-                    and self._stats_export[0] == vkey
-                ):
-                    stats = self._stats_export[1]
-                else:
-                    stats = self._store.window_build_stats()
-                    self._stats_export = (vkey, stats)
-                if stats:
-                    payload["window_build_stats"] = stats
             return payload, dict(self._store.versions)
 
     def _attach_rank_status(self, out: Dict[str, Any]) -> None:

@@ -68,6 +68,11 @@ def parse_token(token: Optional[str]) -> Optional[Dict[str, int]]:
         return None
 
 
+def _last_step(fragment: Dict[str, Any]) -> Optional[int]:
+    view = fragment.get("step_time") or {}
+    return (view.get("coverage") or {}).get("last_step")
+
+
 class SessionPublisher:
     """Owns one session's computer + serialized-fragment cache; thread-safe
     (every HTTP handler thread of the serving tier reads through it)."""
@@ -95,6 +100,9 @@ class SessionPublisher:
         # [token, built_at_monotonic, raw, gzip-or-None]
         self._full_cache: Optional[list] = None
         self._closed = False
+        #: told the newest step each published step_time fragment shows
+        #: (the aggregator's FreshnessTracker, for its own session)
+        self.freshness: Optional[Any] = None
         self.stats: Dict[str, Any] = {
             "polls": 0,
             "builds": {n: 0 for n in FRAGMENT_ORDER},
@@ -156,7 +164,7 @@ class SessionPublisher:
                 raw = json.dumps(obj).encode("utf-8")
                 ser_ns = time.perf_counter_ns() - t0
                 try:  # profiling is garnish — never fail a publish over it
-                    self._computer._store.tick_profile.note_stage(
+                    self._computer.store.tick_profile.note_stage(
                         name, "serialize", ser_ns
                     )
                 except Exception:
@@ -170,6 +178,8 @@ class SessionPublisher:
                     self._frag_versions[name] += 1
                     self.stats["publishes"][name] += 1
                     changed = True
+                    if name == "step_time" and self.freshness is not None:
+                        self.freshness.published(_last_step(obj))
             if changed:
                 self._full_cache = None
                 self._cond.notify_all()

@@ -43,7 +43,7 @@ _FRAGMENT_KEYS: Dict[str, Tuple[str, ...]] = {
     "stdout": ("stdout",),
     "history": ("history",),
     "diagnosis": ("diagnosis", "findings"),
-    "meta": ("ingest", "rank_status", "mesh", "regressions", "window_build"),
+    "meta": ("ingest", "rank_status", "mesh", "regressions"),
 }
 
 #: serving order — also the position of each counter in the version token
@@ -175,6 +175,12 @@ def _meta_fragment(
                 )
                 if k in stats
             }
+            # where the view's lag goes inside the aggregator: the
+            # ingest and serve stages' summaries, without their samples
+            if isinstance(stats.get("freshness"), dict):
+                from traceml_tpu.aggregator.freshness import summaries
+
+                out["ingest"]["freshness"] = summaries(stats["freshness"])
         # per-rank liveness strip (ACTIVE/STALE/LOST/FINISHED): the
         # dashboard shows which ranks a live dip is actually averaging
         status = load_rank_status(session_dir)
@@ -209,13 +215,6 @@ def _meta_fragment(
             out["regressions"] = regressions
     except Exception:
         pass
-    # incremental window-engine health (round 19): per-domain incr-tick
-    # vs full-rebuild counters + invalidation reasons, attached by
-    # payload_with_versions when TRACEML_INCR_WINDOW is on.  Absent key
-    # when the engine is off or never consulted (pre-r19 shape).
-    window_build = payload.get("window_build_stats")
-    if window_build:
-        out["window_build"] = window_build
     return out
 
 

@@ -2,7 +2,9 @@
 (reference: src/traceml_ai/runtime/runtime.py:40-258).
 
 Owns the samplers, the TCP client, and a daemon tick thread at
-``sampler_interval_sec``.  Lifecycle: start → tick loop → (max-steps
+``sampler_interval_sec``.  Each tick runs in a ``traceml/sampler_tick``
+profiler span, each sampler's ``sample()`` in a ``traceml/sample`` span
+inside it (utils/self_trace.py).  Lifecycle: start → tick loop → (max-steps
 DRAINING) → stop: final drain + ``rank_finished`` control marker.
 """
 
@@ -24,6 +26,7 @@ from traceml_tpu.telemetry.control import build_mesh_topology, build_rank_finish
 from traceml_tpu.transport.select import create_transport_client
 from traceml_tpu.transport.tcp_transport import TCPClient
 from traceml_tpu.utils.error_log import get_error_log
+from traceml_tpu.utils.self_trace import span
 
 
 class TraceMLRuntime:
@@ -222,6 +225,10 @@ class TraceMLRuntime:
 
     # -- tick loop -----------------------------------------------------
     def _tick(self) -> None:
+        with span("traceml/sampler_tick"):
+            self._tick_body()
+
+    def _tick_body(self) -> None:
         try:
             from traceml_tpu.dev import chaos
 
@@ -235,7 +242,8 @@ class TraceMLRuntime:
         # — the rank goes quiet (--trace-max-steps contract).
         if phase == "RECORDING":
             for s in self.samplers:
-                s.sample()
+                with span("traceml/sample", sampler=s.name):
+                    s.sample()
         elif phase == "DRAINING":
             for s in self.samplers:
                 if getattr(getattr(s, "_spec", None), "drain_on_recording_stop", False):

@@ -29,7 +29,9 @@ Fault tolerance (docs/developer_guide/fault-tolerance.md):
   spooled), keeping the aggregator's liveness tracker fed.
 
 The publisher also self-observes: per-sampler collect/encode/flush
-nanoseconds, idle-tick ratio, and the transport/spool counters
+nanoseconds, idle-tick ratio, the rank tracer's host time per step and
+its step rows' emission waits (``TraceState.tracer_stats``), and the
+transport/spool counters
 (``reconnects``, ``replayed_envelopes``, ``spool_bytes``), exposed via
 :meth:`stats` and shipped to the aggregator as a ``producer_stats``
 control message (piggybacked on a non-idle batch at most every
@@ -43,6 +45,7 @@ from pathlib import Path
 from typing import Any, Dict, List, Optional
 
 from traceml_tpu.samplers.base_sampler import BaseSampler
+from traceml_tpu.sdk.state import get_state
 from traceml_tpu.telemetry.control import (
     build_producer_stats,
     build_rank_heartbeat,
@@ -54,6 +57,7 @@ from traceml_tpu.transport.spool import DurableSender, ReplaySpool
 from traceml_tpu.transport.tcp_transport import TCPClient
 from traceml_tpu.utils import msgpack_codec
 from traceml_tpu.utils.error_log import get_error_log
+from traceml_tpu.utils.self_trace import span
 
 DEFAULT_HEARTBEAT_INTERVAL_S = 3.0
 
@@ -144,7 +148,12 @@ class TelemetryPublisher:
     def publish(
         self, extra_payloads: Optional[List[Any]] = None, final: bool = False
     ) -> int:
-        """Collect + send; returns number of payloads in the batch."""
+        """Collect + send; returns number of payloads in the batch.  Runs
+        in one ``traceml/publish`` profiler span."""
+        with span("traceml/publish"):
+            return self._publish(extra_payloads, final)
+
+    def _publish(self, extra_payloads: Optional[List[Any]], final: bool) -> int:
         self.ticks += 1
         if not final and not extra_payloads and self._idle():
             self.idle_ticks += 1
@@ -284,6 +293,7 @@ class TelemetryPublisher:
             "idle_ratio": (self.idle_ticks / self.ticks) if self.ticks else 0.0,
             "payloads_sent": self.payloads_sent,
             "samplers": samplers,
+            "tracer": get_state().tracer_stats(),
         }
         transport: Dict[str, Any] = {}
         if self._client is not None:

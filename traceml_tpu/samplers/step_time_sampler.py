@@ -21,6 +21,10 @@ host start to the LAST readiness edge in the step.
 An unresolved step blocks emission (keeps FIFO) until
 ``resolve_timeout_s``; on timeout the step emits host-only (fail-open,
 matches the reference's behavior when CUDA events never resolve).
+
+Each emitted step's wait from its flush (``StepTimeBatch.flushed_at``)
+to its row is kept in ``TraceState.emit_waits``: the sampler tick plus
+the FIFO hold, the rank's share of the live view's lag.
 """
 
 from __future__ import annotations
@@ -184,13 +188,24 @@ class StepTimeSampler(BaseSampler):
                 emit_upto += 1
             else:
                 break  # FIFO: wait for the earliest unresolved step
-        for batch in self._pending[:emit_upto]:
+        self._emit(self._pending[:emit_upto])
+        del self._pending[:emit_upto]
+
+    def _emit(self, batches: List[StepTimeBatch]) -> None:
+        """One row per step, in order; each step's wait from its flush
+        to this emission goes to ``TraceState.emit_waits``."""
+        if not batches:
+            return
+        from traceml_tpu.sdk.state import get_state
+
+        waits = get_state().emit_waits
+        for batch in batches:
             row, self._last_ready = _aggregate_step(batch.events, self._last_ready)
             row["step"] = batch.step
             row["timestamp"] = time.time()
             self.db.add_record(TABLE, row)
             self.steps_emitted += 1
-        del self._pending[:emit_upto]
+            waits.add(time.monotonic(), (time.perf_counter() - batch.flushed_at) * 1e3)
 
     def drain(self) -> None:
         """End-of-run: give the fine-cadence resolver one last bounded
@@ -208,10 +223,5 @@ class StepTimeSampler(BaseSampler):
             time.sleep(0.02)
         for batch in self._pending:
             batch.force_resolve()
-        for batch in self._pending:
-            row, self._last_ready = _aggregate_step(batch.events, self._last_ready)
-            row["step"] = batch.step
-            row["timestamp"] = time.time()
-            self.db.add_record(TABLE, row)
-            self.steps_emitted += 1
+        self._emit(self._pending)
         self._pending.clear()

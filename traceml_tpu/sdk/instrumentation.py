@@ -11,11 +11,16 @@ One ``with trace_step():`` per optimizer step:
   flushes the step's events into the global queue, and submits device
   markers to the background resolver.
 
+Enter and exit each run inside a ``traceml/step_enter`` /
+``traceml/step_exit`` profiler span (utils/self_trace.py) and add their
+host nanoseconds to the step's tracer cost on ``TraceState``.
+
 Never raises into user code; a failure downgrades to a no-op step.
 """
 
 from __future__ import annotations
 
+import time
 from typing import Any, Optional
 
 from traceml_tpu.sdk.state import TraceState, get_state
@@ -23,6 +28,7 @@ from traceml_tpu.sdk.wrappers import publish_region_marker
 from traceml_tpu.utils.error_log import get_error_log
 from traceml_tpu.utils.marker_resolver import get_marker_resolver
 from traceml_tpu.utils.overhead_governor import get_governor
+from traceml_tpu.utils.self_trace import span
 from traceml_tpu.utils.timing import STEP_TIME, TimeEvent, timed_region
 
 
@@ -56,61 +62,73 @@ class trace_step:
         try:
             if st.tls.in_step:
                 return self  # nested: inert (reference: outermost-only)
+            t0 = time.perf_counter_ns()
             self._outermost = True
-            gov = get_governor()
-            # Stamp the previous step's markers from this thread before
-            # opening a new step — see MarkerResolver.sweep_inline.  On
-            # expensive-probe runtimes (remote PJRT: is_ready is an
-            # RPC) the governor moves stamping off the critical path to
-            # the background resolver instead.
-            if gov.allow_inline_sweep():
-                get_marker_resolver().sweep_inline()
-            st.sample_markers = gov.begin_step()
-            st.tls.in_step = True
-            self._step = st.begin_step()
-            st.ensure_mem_tracker().reset(self._step)
-            self._region = timed_region(STEP_TIME, self._step, sink=st.buffer.add)
-            self._region.__enter__()
-            # Back-date the envelope to the previous step's exit so steps
-            # tile the wall clock: the inter-step gap (input fetch, logging)
-            # lands in THIS step's envelope, where its dataloader_next /
-            # user events already land via the flush ordering.
-            if st.last_step_exit is not None:
-                self._region.event.cpu_start = st.last_step_exit
-            st.active_step_event = self._region.event
+            with span("traceml/step_enter", st.current_step + 1):
+                self._enter(st)
+            st.host_ns_open += time.perf_counter_ns() - t0
         except Exception as exc:
             get_error_log().warning("trace_step enter failed", exc)
         return self
 
+    def _enter(self, st: TraceState) -> None:
+        gov = get_governor()
+        # Stamp the previous step's markers from this thread before
+        # opening a new step — see MarkerResolver.sweep_inline.  On
+        # expensive-probe runtimes (remote PJRT: is_ready is an RPC)
+        # the governor moves stamping off the critical path to the
+        # background resolver instead.
+        if gov.allow_inline_sweep():
+            get_marker_resolver().sweep_inline()
+        st.sample_markers = gov.begin_step()
+        st.tls.in_step = True
+        self._step = st.begin_step()
+        st.ensure_mem_tracker().reset(self._step)
+        self._region = timed_region(STEP_TIME, self._step, sink=st.buffer.add)
+        self._region.__enter__()
+        # Back-date the envelope to the previous step's exit so steps
+        # tile the wall clock: the inter-step gap (input fetch, logging)
+        # lands in THIS step's envelope, where its dataloader_next /
+        # user events already land via the flush ordering.
+        if st.last_step_exit is not None:
+            self._region.event.cpu_start = st.last_step_exit
+        st.active_step_event = self._region.event
+
     def __exit__(self, exc_type, exc, tb) -> bool:
         if not self._outermost:
             return False
+        t0 = time.perf_counter_ns()
         st = self._state
+        step = self._step if self._step is not None else st.current_step
         try:
-            st.tls.in_step = False
-            if self._region is not None:
-                self._region.__exit__(exc_type, exc, tb)
-                st.last_step_exit = self._region.event.cpu_end
-                ev = self._region.event
-                if ev.cpu_start is not None and ev.cpu_end is not None:
-                    get_governor().observe_step(ev.cpu_end - ev.cpu_start)
-            st.active_step_event = None
-            step = self._step if self._step is not None else st.current_step
-            if exc_type is None:
-                st.ensure_mem_tracker().record(step)
-            batch = st.flush_step(step)
-            if batch is not None:
-                resolver = get_marker_resolver()
-                for ev in batch.events:
-                    if ev.marker is not None and not ev.marker.resolved:
-                        resolver.submit(ev.marker)
+            with span("traceml/step_exit", step):
+                self._exit(st, step, exc_type, exc, tb)
         except Exception as err:
             get_error_log().warning("trace_step exit failed", err)
         finally:
             # out-of-step instrumentation (eval loops) must never inherit
             # an unsampled step's gate
             st.sample_markers = True
+            st.note_step_host_ns(time.perf_counter_ns() - t0, time.monotonic())
         return False
+
+    def _exit(self, st: TraceState, step: int, exc_type, exc, tb) -> None:
+        st.tls.in_step = False
+        if self._region is not None:
+            self._region.__exit__(exc_type, exc, tb)
+            st.last_step_exit = self._region.event.cpu_end
+            ev = self._region.event
+            if ev.cpu_start is not None and ev.cpu_end is not None:
+                get_governor().observe_step(ev.cpu_end - ev.cpu_start)
+        st.active_step_event = None
+        if exc_type is None:
+            st.ensure_mem_tracker().record(step)
+        batch = st.flush_step(step)
+        if batch is not None:
+            resolver = get_marker_resolver()
+            for ev in batch.events:
+                if ev.marker is not None and not ev.marker.resolved:
+                    resolver.submit(ev.marker)
 
 
 class trace_time:

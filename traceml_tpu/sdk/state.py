@@ -12,6 +12,7 @@ from __future__ import annotations
 import threading
 from typing import Any, Callable, List, Optional
 
+from traceml_tpu.utils.self_trace import TimedRing
 from traceml_tpu.utils.step_memory import StepMemoryTracker
 from traceml_tpu.utils.timing import (
     GLOBAL_STEP_QUEUE,
@@ -45,6 +46,21 @@ class TraceState:
         self.patch_mode: Optional[str] = None
         self.active_step_event: Optional[TimeEvent] = None
         self.compile_events_seen = 0  # bumped by the compile tracker
+        # (compile end, total ms) of each counted compile, so a reader
+        # can count the compiles of any window of the run
+        self.compile_ring = TimedRing(1024)
+        # the tracer's own host time on the training thread: ns inside
+        # trace_step's enter and exit and the step wrapper outside its
+        # jitted call.  host_ns_open gathers the open step's share;
+        # exit folds it into the totals and rings (step exit time, ns)
+        self.host_ns = 0
+        self.host_steps = 0
+        self.host_ns_open = 0
+        self.host_ns_ring = TimedRing(8192)
+        # (emission time, ms) per step row the step-time sampler emits:
+        # the wait from the step's flush to its row (sampler tick + the
+        # FIFO hold until its device markers resolve)
+        self.emit_waits = TimedRing(8192)
         # wall-clock of the previous trace_step exit: successive steps
         # tile the wall clock, so inter-step host time (input fetch in the
         # idiomatic `for batch in loader: with trace_step():` pattern) is
@@ -87,6 +103,28 @@ class TraceState:
     def current_step(self) -> int:
         with self._lock:
             return self.step_counter
+
+    def note_step_host_ns(self, ns: int, now: float) -> None:
+        """Close the step's tracer host time (training thread only)."""
+        total = self.host_ns_open + ns
+        self.host_ns_open = 0
+        self.host_ns += total
+        self.host_steps += 1
+        self.host_ns_ring.add(now, total)
+
+    def tracer_stats(self) -> dict:
+        """Host time per step and the emission waits, for the
+        publisher's ``stats()``."""
+        steps = self.host_steps
+        waits = self.emit_waits.summary()
+        return {
+            "host_ns": self.host_ns,
+            "host_steps": steps,
+            "host_us_per_step": self.host_ns / steps / 1e3 if steps else None,
+            "emit_wait_ms_p50": waits["p50"],
+            "emit_wait_ms_p95": waits["p95"],
+            "compile_events_seen": self.compile_events_seen,
+        }
 
     def ensure_mem_tracker(self) -> StepMemoryTracker:
         mt = self.mem_tracker  # tracelint: unguarded(double-checked init fast path; None race falls through to the locked slow path)

@@ -15,16 +15,23 @@ same information — scrapped because ``Compiled.call`` re-flattens the
 arg pytree in Python (~5 ms/step on a 65-leaf train state) and misses
 compiles outside the wrapped function.
 
+The wrapper's own host work outside the jitted call (picking the
+marker handles, the envelope hand-off) runs in a ``traceml/wrap``
+profiler span and counts toward the step's tracer cost on
+``TraceState``.
+
 Fail-open: the wrapper never raises on its own behalf; user errors
 propagate untouched.
 """
 
 from __future__ import annotations
 
+import time
 from typing import Any, Callable, Dict, Optional, Tuple
 
 from traceml_tpu.sdk.state import TraceState, get_state
 from traceml_tpu.sdk.wrappers import publish_region_marker
+from traceml_tpu.utils.self_trace import span
 from traceml_tpu.utils.timing import COMPUTE_TIME, DeviceMarker, timed_region
 
 
@@ -202,9 +209,18 @@ class WrappedStepFn:
             self._flops_pending = False
             if st.flops_per_step is None:  # a manual value wins
                 self.estimate_flops(*args, **kwargs)
-        region = timed_region(self._phase, st.current_step, sink=st.buffer.add)
-        with region as tr:
+        t0 = time.perf_counter_ns()
+        step = st.current_step
+        region = timed_region(self._phase, step, sink=st.buffer.add)
+        tr = region.__enter__()
+        t1 = time.perf_counter_ns()
+        try:
             out = self._jfn(*args, **kwargs)
+        except BaseException as exc:
+            region.__exit__(type(exc), exc, exc.__traceback__)
+            raise
+        t2 = time.perf_counter_ns()
+        with span("traceml/wrap", step):
             # ONE marker shared by the compute event and the open step
             # envelope (same handles, same dispatch instant) — a single
             # pytree flatten and a single resolver poll per step.  The
@@ -223,10 +239,13 @@ class WrappedStepFn:
                     # they must not feed the lifetime EMA
                     marker.step_end_hint = st.tls.in_step
                     tr.event.marker = marker
-        # envelope hand-off + dispatch-time resolver submission (the
-        # fine-cadence stamping that intra-step device edges need) —
-        # see publish_region_marker's docstring
-        publish_region_marker(region.event, st)
+            region.__exit__(None, None, None)
+            # envelope hand-off + dispatch-time resolver submission (the
+            # fine-cadence stamping that intra-step device edges need) —
+            # see publish_region_marker's docstring
+            publish_region_marker(region.event, st)
+        if st.tls.in_step:
+            st.host_ns_open += (t1 - t0) + (time.perf_counter_ns() - t2)
         return out
 
 

@@ -144,6 +144,9 @@ class TCPServer:
         # the consumer attribute corrupt frames to the client that sent
         # them instead of one server-wide counter
         self._pending: List[Tuple[str, bytes]] = []
+        # monotonic arrival time of each pending frame, in lockstep
+        # (the aggregator's freshness tracker starts its clock here)
+        self._arrivals: List[float] = []
         self._data_event = threading.Event()
         self._clients: Dict[int, _ClientBuffer] = {}
         self._peers: Dict[int, str] = {}
@@ -287,21 +290,25 @@ class TCPServer:
         :meth:`pending_frames`), so one drain call can't hold the caller
         hostage decoding an unbounded backlog.
         """
-        return [frame for _peer, frame in self.drain_tagged(max_frames)]
+        return [frame for _peer, frame in self.drain_timed(max_frames)[0]]
 
-    def drain_tagged(
+    def drain_timed(
         self, max_frames: Optional[int] = None
-    ) -> List[Tuple[str, bytes]]:
+    ) -> Tuple[List[Tuple[str, bytes]], List[float]]:
         """:meth:`drain`, keeping each frame's peer tag ("ip:port") so
-        the consumer can attribute decode failures per client."""
+        the consumer can attribute decode failures per client, and its
+        arrival time (``time.monotonic()`` when the serve thread queued
+        it)."""
         with self._lock:
             if max_frames is None or len(self._pending) <= max_frames:
-                out = self._pending
-                self._pending = []
+                out, times = self._pending, self._arrivals
+                self._pending, self._arrivals = [], []
             else:
                 out = self._pending[:max_frames]
+                times = self._arrivals[:max_frames]
                 del self._pending[:max_frames]
-        return out
+                del self._arrivals[:max_frames]
+        return out, times
 
     def pending_frames(self) -> int:
         """Frames buffered by the selector thread, awaiting drain()."""
@@ -320,7 +327,7 @@ class TCPServer:
         return self._unwrap_compressed(payloads, "unknown")
 
     def decode_tagged(self, tagged: List[Tuple[str, bytes]]) -> List[Any]:
-        """Per-frame decode of :meth:`drain_tagged` output.  A corrupt
+        """Per-frame decode of :meth:`drain_timed`'s frames.  A corrupt
         frame is skipped (its whole batch of envelopes is lost — msgpack
         cannot partially decode) and counted against the peer that sent
         it in ``corrupt_frame_drops``; the connection stays up."""
@@ -416,12 +423,14 @@ class TCPServer:
             return
         if not tagged:
             return
+        now = time.monotonic()
         with self._lock:
             self.frames_received += len(tagged)
             self.frames_by_transport["shm"] = (
                 self.frames_by_transport.get("shm", 0) + len(tagged)
             )
             self._pending.extend(tagged)
+            self._arrivals.extend([now] * len(tagged))
             if len(self._pending) > self.pending_hwm:
                 self.pending_hwm = len(self._pending)
         self._data_event.set()
@@ -515,6 +524,7 @@ class TCPServer:
         # NO decode here: this is the selector thread, shared by every
         # client.  Frames are handed to the consumer as-is.
         kind = "uds" if peer.startswith("uds:") else "tcp"
+        now = time.monotonic()
         with self._lock:
             self.frames_received += len(frames)
             self.frames_by_transport[kind] = (
@@ -522,6 +532,7 @@ class TCPServer:
             )
             for frame in frames:
                 self._pending.append((peer, frame))
+            self._arrivals.extend([now] * len(frames))
             if len(self._pending) > self.pending_hwm:
                 self.pending_hwm = len(self._pending)
         self._data_event.set()

@@ -1906,10 +1906,10 @@ class TickProfile:
     Extends r19's :class:`WindowBuildStats` from "where did the window
     build go" to "where did the whole tick go": refresh → build →
     diagnose → attribute → view → serialize.  Lives on the snapshot
-    store and is surfaced through the same ``window_build`` meta
-    fragment / final-report channel, so per-stage overhead is visible
-    without attaching a profiler (the T3 motivation: the observer's own
-    cost must itself be observable)."""
+    store and is read through ``LiveSnapshotStore.window_build_stats()``
+    and the final report's ``meta.window_build``, so per-stage overhead
+    is visible without attaching a profiler (the T3 motivation: the
+    observer's own cost must itself be observable)."""
 
     __slots__ = ("ticks", "stage_ns", "counters")
 

@@ -21,6 +21,7 @@ from typing import List, Optional
 
 from traceml_tpu.utils.error_log import get_error_log
 from traceml_tpu.utils.overhead_governor import get_governor
+from traceml_tpu.utils.self_trace import span
 from traceml_tpu.utils.timing import DeviceMarker
 
 _DEFAULT_INTERVAL = 0.002  # 2 ms poll while young markers are pending
@@ -36,7 +37,18 @@ def _poll_batch(pending: List[DeviceMarker]) -> tuple:
     starvation, not the probe).  No-op polls of already-resolved markers
     and exception-path polls are excluded from the sample.  Returns
     (#resolved-by-this-batch, min_probe_dt | None).  Shared by
-    sweep_inline (main thread) and the resolver loop."""
+    sweep_inline (main thread) and the resolver loop; the polls run in
+    one ``traceml/resolver_poll`` profiler span."""
+    with span("traceml/resolver_poll"):
+        resolved, best = _poll_each(pending)
+    # this is THE signal that detects RPC-priced is_ready (remote
+    # PJRT) and turns inline sweeping off / stretches the marker stride
+    if best is not None:
+        get_governor().observe_probe(best, 1)
+    return resolved, best
+
+
+def _poll_each(pending: List[DeviceMarker]) -> tuple:
     resolved = 0
     best = None
     for m in pending:
@@ -52,10 +64,6 @@ def _poll_batch(pending: List[DeviceMarker]) -> tuple:
         dt = time.perf_counter() - t0
         if best is None or dt < best:
             best = dt
-    # this is THE signal that detects RPC-priced is_ready (remote
-    # PJRT) and turns inline sweeping off / stretches the marker stride
-    if best is not None:
-        get_governor().observe_probe(best, 1)
     return resolved, best
 
 

@@ -16,7 +16,8 @@ The reference resets ``torch.cuda`` peak stats at step start and reads
 Backends are pluggable because ``memory_stats()`` returns ``None`` on
 some runtimes (CPU, remote devices): the live-arrays backend sums
 ``jax.live_arrays()`` nbytes per device, and tests inject a deterministic
-fake (SURVEY.md §4 "fake device layer").
+fake (SURVEY.md §4 "fake device layer").  Each backend sample runs in a
+``traceml/mem_read`` profiler span (utils/self_trace.py).
 """
 
 from __future__ import annotations
@@ -25,6 +26,7 @@ import time
 from typing import Any, Dict, List, Optional, Protocol
 
 from traceml_tpu.utils.error_log import get_error_log
+from traceml_tpu.utils.self_trace import span
 from traceml_tpu.utils.timing import push_step_memory_row
 
 
@@ -254,6 +256,8 @@ def device_memory_rows(backend_holder: Dict[str, Any], ts: float) -> List[Dict[s
         except Exception:
             return []
         backend_holder["backend"] = backend
+    with span("traceml/mem_read"):
+        sampled = backend.sample()
     return [
         {
             "timestamp": ts,
@@ -263,7 +267,7 @@ def device_memory_rows(backend_holder: Dict[str, Any], ts: float) -> List[Dict[s
             "memory_peak_bytes": r.get("peak_bytes"),
             "memory_total_bytes": r.get("limit_bytes"),
         }
-        for r in backend.sample()
+        for r in sampled
     ]
 
 
@@ -301,7 +305,9 @@ class StepMemoryTracker:
         if self._have_edge:
             return
         try:
-            self._step_start = {row["device_id"]: row for row in self._backend.sample()}
+            with span("traceml/mem_read", step):
+                start_rows = self._backend.sample()
+            self._step_start = {row["device_id"]: row for row in start_rows}
             self._have_edge = True
         except Exception as exc:
             get_error_log().warning("step memory reset failed", exc)
@@ -324,7 +330,8 @@ class StepMemoryTracker:
         rows: List[Dict[str, Any]] = []
         try:
             ts = time.time()
-            end_rows = self._backend.sample()
+            with span("traceml/mem_read", step):
+                end_rows = self._backend.sample()
             for row in end_rows:
                 start = self._step_start.get(row["device_id"], {})
                 step_peak = max(
