@@ -146,3 +146,70 @@ def test_stats_not_emitted_every_batch(tmp_path):
         pub.publish()
     payloads, _ = msgpack_codec.decode_batch(client.bodies)
     assert not any(p.get(CONTROL_KEY) == PRODUCER_STATS for p in payloads)
+
+
+class OtherSampler(FakeSampler):
+    name = "other"
+
+
+def test_publish_units_ships_only_named_units_and_counts_lane(tmp_path):
+    fake, other = FakeSampler(disk_backup_dir=tmp_path), OtherSampler(disk_backup_dir=tmp_path)
+    client = CapturingClient()
+    pub = TelemetryPublisher([fake, other], client, SenderIdentity(), stats_interval_s=3600.0)
+    fake.sample()
+    fake.sample()
+    other.sample()
+    # 2 = the fake sampler's envelope + the one-shot transport_hello
+    assert pub.publish_units(("fake",)) == 2
+    assert pub.publish_units(("fake",)) == 0  # nothing new: no frame
+    payloads, _ = msgpack_codec.decode_batch(client.bodies)
+    envs = [e for e in map(normalize_telemetry_envelope, payloads) if e]
+    assert [e.sampler for e in envs] == ["fake"]
+    assert envs[0].tables["t"] == [{"i": 0}, {"i": 1}]
+    assert other.sender.dirty()  # left for the full tick
+    st = pub.stats()
+    assert (st["lane_publishes"], st["lane_rows"]) == (1, 0)  # rows of table "fake" only
+    assert st["ticks"] == 0 and st["samplers"]["fake"]["envelopes"] == 1
+    pub.publish()  # the full tick ships the rest, once
+    payloads, _ = msgpack_codec.decode_batch(client.bodies)
+    envs = [e for e in map(normalize_telemetry_envelope, payloads) if e]
+    assert [e.sampler for e in envs] == ["fake", "other"]
+    assert pub.stats()["lane_publishes"] == 1
+
+
+def test_publish_units_counts_rows_of_the_samplers_own_table():
+    class StepLike(FakeSampler):
+        name = "step_time"
+
+        def _sample(self):
+            self.db.add_record("step_time", {"step": self._i})
+            self.db.add_record("model_stats", {"flops": 1.0})
+            self._i += 1
+
+    s = StepLike(disk_backup_dir=None)
+    pub = TelemetryPublisher([s], CapturingClient(), SenderIdentity())
+    for _ in range(3):
+        s.sample()
+    pub.publish_units(("step_time",))
+    s.sample()
+    pub.publish_units(("step_time",))
+    st = pub.stats()
+    assert (st["lane_publishes"], st["lane_rows"]) == (2, 4)
+
+
+def test_publish_units_leaves_the_backup_throttle_to_full_ticks(tmp_path):
+    """Lane publishes buffer their envelope for the disk backup without
+    advancing its flush_every throttle, which counts full ticks only."""
+    s = FakeSampler(disk_backup_dir=tmp_path)
+    pub = TelemetryPublisher([s], None, SenderIdentity())
+    for _ in range(25):
+        s.sample()
+        pub.publish_units(("fake",))
+    assert s.writer.has_pending()
+    assert not (tmp_path / "fake" / ENVELOPE_FILE).exists()
+    for _ in range(19):
+        pub.publish()  # pending backup keeps these ticks non-idle
+    assert not (tmp_path / "fake" / ENVELOPE_FILE).exists()
+    pub.publish()  # the 20th full tick writes every buffered frame
+    got = list(iter_backup_tables(tmp_path / "fake" / ENVELOPE_FILE))
+    assert got == [("t", {"i": i}) for i in range(25)]

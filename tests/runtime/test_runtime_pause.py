@@ -29,3 +29,25 @@ def test_runtime_pause_resume(tmp_path):
         assert step_sampler.db.append_count("system") > paused_count
     finally:
         rt.stop()
+
+
+def test_pause_holds_lane_rows_until_resume(lane_runtime):
+    """Steps flushed while paused are neither sampled nor published by
+    the step-row lane; resume() ships every one of them, in order."""
+    from traceml_tpu.sdk.instrumentation import trace_step
+    from traceml_tpu.utils.timing import GLOBAL_STEP_QUEUE
+
+    rt, client = lane_runtime
+    rt.pause()
+    for _ in range(5):
+        with trace_step():
+            pass
+    time.sleep(0.3)
+    assert client.step_rows() == []
+    assert GLOBAL_STEP_QUEUE.qsize() == 5  # not even drained
+    rt.resume()
+    deadline = time.monotonic() + 0.5
+    while time.monotonic() < deadline and len(client.step_rows()) < 5:
+        time.sleep(0.005)
+    assert [step for _, _, step, _ in client.step_rows()] == [1, 2, 3, 4, 5]
+    assert rt.publisher.stats()["ticks"] == 0  # the lane, not a tick

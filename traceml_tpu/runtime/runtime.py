@@ -1,17 +1,25 @@
 """Per-rank runtime agent
 (reference: src/traceml_ai/runtime/runtime.py:40-258).
 
-Owns the samplers, the TCP client, and a daemon tick thread at
-``sampler_interval_sec``.  Each tick runs in a ``traceml/sampler_tick``
-profiler span, each sampler's ``sample()`` in a ``traceml/sample`` span
-inside it (utils/self_trace.py).  Lifecycle: start → tick loop → (max-steps
-DRAINING) → stop: final drain + ``rank_finished`` control marker.
+Owns the samplers, the TCP client, and one daemon thread with two kinds
+of work (docs/developer_guide/rank-producer-path.md):
+
+* the **full tick**, on a fixed schedule of ``sampler_interval_sec``:
+  every sampler, the mesh topology, ``rank_finished``, the heartbeat;
+* the **step-row lane**, woken by each step flush: only the step-time
+  sampler, then only its rows, at most once per :data:`LANE_GAP_S`.
+
+Each tick runs in a ``traceml/sampler_tick`` profiler span, each
+sampler's ``sample()`` in a ``traceml/sample`` span (utils/self_trace.py).
+Lifecycle: start → loop → (max-steps DRAINING) → stop: final drain +
+``rank_finished`` control marker.
 """
 
 from __future__ import annotations
 
 import threading
-from typing import List, Optional
+import time
+from typing import Callable, List, Optional
 
 from traceml_tpu.config import flags
 from traceml_tpu.runtime.identity import RuntimeIdentity, resolve_runtime_identity
@@ -27,6 +35,13 @@ from traceml_tpu.transport.select import create_transport_client
 from traceml_tpu.transport.tcp_transport import TCPClient
 from traceml_tpu.utils.error_log import get_error_log
 from traceml_tpu.utils.self_trace import span
+
+#: Least time between two step-row lane frames.  The live view refreshes
+#: at most every 0.2 s (``renderers/serving.py`` ``min_poll_interval``)
+#: and the aggregator commits every 0.2 s, so a finer rank cadence cannot
+#: make the view fresher; it only adds frames.  Rows that resolve inside
+#: the gap share the next frame.
+LANE_GAP_S = 0.1
 
 
 class TraceMLRuntime:
@@ -56,6 +71,12 @@ class TraceMLRuntime:
         self._paused = threading.Event()
         self._tick_lock = threading.Lock()  # pause() waits on in-flight ticks
         self._lock = threading.Lock()
+        # step-row lane: set by every step flush (training thread), waited
+        # on by the runtime thread; everything else of the lane lives on
+        # the runtime thread
+        self._step_flushed = threading.Event()
+        self._step_sampler: Optional[BaseSampler] = None
+        self._lane_sent_at = float("-inf")
 
     # -- lifecycle -----------------------------------------------------
     def start(self) -> None:
@@ -98,6 +119,11 @@ class TraceMLRuntime:
         )
         # max-steps lifecycle: observe sdk step flushes
         get_state().on_step_flushed.append(self.recording.on_step_flushed)
+        self._step_sampler = next(
+            (s for s in self.samplers if s.name == "step_time"), None
+        )
+        self._step_flushed.clear()
+        get_state().on_step_flushed.append(self._wake_lane)
         # on-demand XLA profiler capture (control-file protocol)
         try:
             from traceml_tpu.sdk.profile_capture import ProfileCaptureService
@@ -125,6 +151,7 @@ class TraceMLRuntime:
                 return
             self._started = False
         self._stop_evt.set()
+        self._step_flushed.set()
         if self._thread is not None:
             self._thread.join(timeout=max(5.0, self.settings.sampler_interval_sec * 3))
             self._thread = None
@@ -143,10 +170,11 @@ class TraceMLRuntime:
                 pass
         if self.client is not None:
             self.client.close()
-        try:
-            get_state().on_step_flushed.remove(self.recording.on_step_flushed)
-        except ValueError:
-            pass
+        for cb in (self.recording.on_step_flushed, self._wake_lane):
+            try:
+                get_state().on_step_flushed.remove(cb)
+            except ValueError:
+                pass
         if getattr(self, "_profile_service", None) is not None:
             try:
                 get_state().on_step_flushed.remove(
@@ -222,6 +250,30 @@ class TraceMLRuntime:
 
     def resume(self) -> None:
         self._paused.clear()
+        # rows held through the pause leave on the lane, not a tick later
+        self._step_flushed.set()
+
+    # -- step-row lane -------------------------------------------------
+    def _wake_lane(self, step: int) -> None:
+        """``TraceState.on_step_flushed`` callback (training thread): wake
+        the runtime thread and nothing else.  A set event skips the lock
+        inside ``Event.set``; the lane clears it before draining, so a
+        step queued before this check is drained by that lane pass."""
+        if not self._step_flushed.is_set():
+            self._step_flushed.set()
+
+    def _lane(self) -> None:
+        """Emit the step rows that resolved since the last pass and ship
+        them alone: ``StepTimeSampler.sample()`` (drain, resolve in FIFO
+        order, emit), then the step-time unit only.  Runs in RECORDING
+        only; never polls a marker itself."""
+        sampler = self._step_sampler
+        if sampler is None or self.recording.phase != "RECORDING":
+            return
+        with span("traceml/sample", sampler=sampler.name):
+            sampler.sample()
+        if self.publisher.publish_units((sampler.name,)):
+            self._lane_sent_at = time.monotonic()
 
     # -- tick loop -----------------------------------------------------
     def _tick(self) -> None:
@@ -263,15 +315,42 @@ class TraceMLRuntime:
             self.publisher.publish(extra)
 
     def _sampler_loop(self) -> None:
+        """Full ticks on a fixed deadline schedule; between them, wait on
+        step flushes and run the lane, coalesced to one frame per
+        :data:`LANE_GAP_S`.  Lane wakes never move a tick's deadline."""
         interval = max(0.05, self.settings.sampler_interval_sec)
-        while not self._stop_evt.wait(interval):
-            if self._paused.is_set():
-                continue
-            try:
-                with self._tick_lock:
-                    self._tick()
-            except Exception as exc:  # belt+braces; samplers fail-open anyway
-                get_error_log().warning("runtime tick failed", exc)
+        clock = time.monotonic
+        next_tick = clock() + interval
+        while True:
+            # sleep out the gap since the last lane frame first: a flush
+            # inside it only sets the event, and wakes no thread
+            hold = min(self._lane_sent_at + LANE_GAP_S, next_tick) - clock()
+            if hold > 0 and self._stop_evt.wait(hold):
+                return
+            woke = self._step_flushed.wait(max(0.0, next_tick - clock()))
+            if self._stop_evt.is_set():
+                return
+            # clear before sampling: a flush after this wakes the next pass
+            self._step_flushed.clear()
+            now = clock()
+            if now >= next_tick:
+                while next_tick <= now:
+                    next_tick += interval
+                self._run_paused_aware(self._tick)
+            elif woke:
+                self._run_paused_aware(self._lane)
+
+    def _run_paused_aware(self, work: Callable[[], None]) -> None:
+        """Run a tick or a lane pass under ``_tick_lock`` unless paused
+        (checked under the lock, so nothing starts once ``pause()``
+        returns)."""
+        try:
+            with self._tick_lock:
+                if self._paused.is_set():
+                    return
+                work()
+        except Exception as exc:  # belt+braces; samplers fail-open anyway
+            get_error_log().warning("runtime tick failed", exc)
 
     def _final_drain(self) -> None:
         """Shutdown: drain every sampler, publish leftovers + rank_finished."""

@@ -42,7 +42,7 @@ from __future__ import annotations
 
 import time
 from pathlib import Path
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Sequence
 
 from traceml_tpu.samplers.base_sampler import BaseSampler
 from traceml_tpu.sdk.state import get_state
@@ -97,6 +97,9 @@ class TelemetryPublisher:
         self.ticks = 0
         self.idle_ticks = 0
         self.payloads_sent = 0
+        # frames and step rows the step-row lane shipped (publish_units)
+        self.lane_publishes = 0
+        self.lane_rows = 0
         self._stats_interval = stats_interval_s
         self._last_stats_emit = time.monotonic()
         self._heartbeat_interval = max(0.25, float(heartbeat_interval_s))
@@ -160,14 +163,50 @@ class TelemetryPublisher:
             self._maybe_heartbeat()
             return 0
         batch: List[Any] = []
+        self._collect(self._units, batch, final, backup_flush=True)
+        if extra_payloads:
+            for p in extra_payloads:
+                self._stamp_seq(p)
+            batch.extend(extra_payloads)
+        return self._send(batch, final)
+
+    def publish_units(self, names: Sequence[str]) -> int:
+        """Collect + send only the named samplers' units: the runtime's
+        step-row lane (``TraceMLRuntime``) ships resolved step rows
+        between full ticks through here.  Same single encode, seq
+        stamping, durable sender and per-sampler stats as
+        :meth:`publish`; not a tick (``ticks`` / ``idle_ticks`` keep
+        counting full ticks only), and the disk backup is appended but
+        not flushed, so its ``flush_every`` throttle keeps counting full
+        ticks.  Returns the number of payloads sent."""
+        with span("traceml/publish"):
+            units = [u for u in self._units if u[0].name in names]
+            batch: List[Any] = []
+            rows = self._collect(units, batch, False, backup_flush=False)
+            if not batch:
+                return 0
+            self.lane_publishes += 1
+            self.lane_rows += rows
+            return self._send(batch, False)
+
+    def _collect(
+        self, units: List[tuple], batch: List[Any], final: bool, backup_flush: bool
+    ) -> int:
+        """Append each unit's encoded payload to ``batch`` and its disk
+        backup; returns the rows of each sampler's own table (the
+        ``step_time`` table for the step-time sampler)."""
+        rows = 0
         perf = time.perf_counter_ns
-        for s, sender, writer, st in self._units:
+        for s, sender, writer, st in units:
             try:
                 t0 = perf()
                 payload = sender.collect_payload()
                 t1 = perf()
                 st["collect_ns"] += t1 - t0
                 if payload is not None:
+                    own = payload["body"]["tables"].get(s.name)
+                    if own is not None:
+                        rows += own["n"]
                     self._stamp_seq(payload)
                     enc = msgpack_codec.preencode(payload)
                     t2 = perf()
@@ -179,10 +218,11 @@ class TelemetryPublisher:
                     else:
                         batch.append(enc)
                     writer.append_envelope(enc)
-                    t3 = perf()
-                    writer.flush(force=final)
-                    st["flush_ns"] += perf() - t3
-                elif final or writer.has_pending():
+                    if backup_flush:
+                        t3 = perf()
+                        writer.flush(force=final)
+                        st["flush_ns"] += perf() - t3
+                elif backup_flush and (final or writer.has_pending()):
                     # nothing collected but buffered backup frames (or a
                     # final drain) still need the flush throttle to run
                     t3 = perf()
@@ -192,10 +232,10 @@ class TelemetryPublisher:
                 get_error_log().warning(
                     f"collect failed for sampler {s.name}", exc
                 )
-        if extra_payloads:
-            for p in extra_payloads:
-                self._stamp_seq(p)
-            batch.extend(extra_payloads)
+        return rows
+
+    def _send(self, batch: List[Any], final: bool) -> int:
+        """Hello and stats piggyback, then one frame; returns its size."""
         if batch:
             hello = self._take_hello()
             if hello is not None:
@@ -292,6 +332,8 @@ class TelemetryPublisher:
             "idle_ticks": self.idle_ticks,
             "idle_ratio": (self.idle_ticks / self.ticks) if self.ticks else 0.0,
             "payloads_sent": self.payloads_sent,
+            "lane_publishes": self.lane_publishes,
+            "lane_rows": self.lane_rows,
             "samplers": samplers,
             "tracer": get_state().tracer_stats(),
         }
