@@ -3,9 +3,12 @@
 The program under test is driven as a user's training script drives it:
 the aggregator in a process of its own (``dashboard`` mode, through the
 launcher's env contract), the runtime agent and ``init(mode="auto")`` in
-this process, ``wrap_step_fn(make_train_step(...), donate_argnums=(0,))``
-called inside ``trace_step`` with the loss read on the host every step.
-A poller process reads ``/api/live`` throughout.
+this process, ``wrap_step_fn(train_step, donate_argnums=(0,))`` called
+inside ``trace_step`` with the loss read on the host every step. A
+poller process reads ``/api/live`` throughout. What is particular to an
+architecture is its family's (``families/<family>/``, named by the
+configuration): the program's ``build`` gives the step and its state,
+the family's reference the model the reference follows.
 
 Set-up builds the one step object and its state, replaces the program's
 initial weights with the benchmark's own (``weights.py``), and drives it
@@ -36,11 +39,9 @@ from typing import Dict, List, Optional
 import jax
 
 import traceml_tpu
-from traceml_tpu.models import ModelConfig, init_train_state, make_train_step
 from traceml_tpu.parallel.mesh import batch_sharding, make_mesh
 
-from benchmark import check, discovery, reference, trace_reduce
-from benchmark.flops import train_step_flops
+from benchmark import check, discovery, reference, trace_program, trace_reduce
 from benchmark.peaks import peaks_for
 from benchmark.weights import init_params, make_params, seed_key, token_pool
 
@@ -178,24 +179,6 @@ class Stack:
 # -- the program -------------------------------------------------------------
 
 
-def model_config(spec: dict) -> ModelConfig:
-    """The program's ModelConfig for the cell, checked against the widths
-    the configuration file states."""
-    m = spec["model"]
-    cfg = ModelConfig(
-        vocab_size=m["vocab_size"], hidden=m["hidden_size"],
-        n_layers=m["num_hidden_layers"], n_heads=m["num_attention_heads"],
-        n_kv_heads=m["num_key_value_heads"], ffn_mult=m["program"]["ffn_mult"],
-        max_seq_len=spec["load"]["seq"], rope_theta=float(m["rope_theta"]),
-    )
-    if (cfg.head_dim, cfg.ffn_hidden) != (m["head_dim"], m["intermediate_size"]):
-        raise ValueError(
-            f"ModelConfig gives head_dim {cfg.head_dim}, ffn {cfg.ffn_hidden}; "
-            f"the configuration states {m['head_dim']}, {m['intermediate_size']}"
-        )
-    return cfg
-
-
 def _first_moment(opt_state):
     """Adam's first moment, wherever the optimizer keeps it."""
     found = [
@@ -214,15 +197,12 @@ class Program:
     def __init__(self, spec: dict, seed: int, devices: list) -> None:
         m, load, cell = spec["model"], spec["load"], spec["cell"]
         self.spec, self.seed, self.devices = spec, seed, devices
-        cfg = model_config(spec)
         self.mesh = make_mesh(cell["mesh"], devices=devices) if cell.get("mesh") else None
         key = jax.random.key_data(seed_key(seed))
-        model, state, tx = init_train_state(
-            cfg, key, learning_rate=m["optimizer"]["learning_rate"], mesh=self.mesh
-        )
+        fn, state = spec["family"].program.build(spec, key, self.mesh)
         params = state["params"]
         shapes = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), params)
-        want = reference.param_shapes(m)
+        want = spec["family"].reference.param_shapes(m)
         if reference.leaf_paths(shapes) != reference.leaf_paths(want) or [
             (s.shape, s.dtype) for s in jax.tree.leaves(shapes)
         ] != [(s.shape, s.dtype) for s in jax.tree.leaves(want)]:
@@ -237,7 +217,6 @@ class Program:
         self.pool_np = token_pool(seed, POOL, load["batch"], load["seq"], m["vocab_size"])
         sharding = batch_sharding(self.mesh) if self.mesh is not None else devices[0]
         self.pool = [jax.device_put(b, sharding) for b in self.pool_np]
-        fn = make_train_step(model, tx, mesh=self.mesh)
         self.step = traceml_tpu.wrap_step_fn(fn, donate_argnums=(0,))
         self._fn = fn
         self.bare_step = None
@@ -320,7 +299,7 @@ class Program:
 def reference_readings(spec: dict, program: Program, devices: list, variant="f32") -> Dict:
     m = spec["model"]
     return reference.readings(
-        m, m["optimizer"], m["init_std"], seed_key(program.seed),
+        spec["family"].reference, m, m["optimizer"], m["init_std"], seed_key(program.seed),
         program.pool_np[:CHECK_STEPS], variant, devices,
     )
 
@@ -373,12 +352,16 @@ def run(name: str, seed: int, seconds: float, trace: bool, t_start: float,
         window_steps = [s for s, _ in prog.completions[first:]]
 
         record = {
+            "spec": {"model": spec["model"], "load": load},
+            "peaks": peaks_for(devices[0].device_kind) if require_tpu else None,
             "chips": len(devices), "seconds": seconds,
             "setup_s": setup_s, "aggregator_ready_s": stack.ready_s,
             "first_step_s": first_step_s, "setup_split": split,
             "window": {"start": w0, "stop": w1, "step_s": step_s},
             "tokens_per_step": load["batch"] * (load["seq"] - 1),
-            "flops_per_step": train_step_flops(spec["model"], load["batch"], load["seq"]),
+            "flops_per_step": spec["family"].reference.train_step_flops(
+                spec["model"], load["batch"], load["seq"]
+            ),
             "sampler_us": us1 - us0,
         }
         breakdown = None
@@ -401,7 +384,6 @@ def run(name: str, seed: int, seconds: float, trace: bool, t_start: float,
     numbers = dict(check.gaps(readings, ref), missing_steps=missing)
     limits = dict(spec["cell"]["limits"], missing_steps=0)
     kind = devices[0].device_kind
-    record["peak_flops"] = peaks_for(kind)["bf16_flops"] if require_tpu else None
     metrics = discovery.read_metrics(discovery.metrics_for(name, trace), record)
     device = {
         "platform": devices[0].platform, "kind": kind, "count": len(devices),
@@ -456,6 +438,8 @@ def _profile(prog: Program, logs: Path, step_s: float) -> Dict:
                 prog.attached_annotated()
     finally:
         jax.profiler.stop_trace()
-    reduced = trace_reduce.reduce(trace_reduce.load(trace_reduce.find_xplane(trace_dir)))
+    path = trace_reduce.find_xplane(trace_dir)
+    reduced = trace_reduce.reduce(trace_reduce.load(path))
+    reduced["program"] = trace_program.reduce(trace_program.load(path))
     reduced["steps"] = n
     return reduced

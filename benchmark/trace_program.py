@@ -1,6 +1,7 @@
 """What the program's own scopes and spans say in a profiler trace
-(``.xplane.pb``): device time by train-step phase, and the device's idle
-time set against the tracer's ``traceml/`` host spans.
+(``.xplane.pb``): device time by train-step phase and by named scope,
+and the device's idle time set against the tracer's ``traceml/`` host
+spans.
 
 Events are read with ``ProfileData``, as ``trace_reduce`` reads them.
 ``ProfileData`` does not carry an operation's name stack, so a small
@@ -10,7 +11,11 @@ An operation's phase comes from that name stack, which
 ``make_train_step``'s scopes write:
 ``…/jvp(forward)/…`` is ``forward``, ``…/transpose(jvp(forward))/…``
 is ``backward``, ``…/optimizer/…`` is ``optimizer``, anything else
-``other``.
+``other``. Its scopes are the names of its name stack but the last (the
+operation), each stripped of the transformations around it
+(``jit(train_step)`` is ``train_step``, ``transpose(jvp(forward))`` is
+``forward``); it runs in the backward pass when one of them is under
+``transpose(…)``.
 
 The window, the operations and the gaps are ``trace_reduce``'s; a gap
 goes to the ``traceml/`` span that overlaps it most, else to the
@@ -19,6 +24,7 @@ harness span that does (``trace_reduce.attribute``), else ``other``.
 
 from __future__ import annotations
 
+import re
 from collections import defaultdict
 from pathlib import Path
 from typing import Dict, Iterator, List, Tuple
@@ -30,6 +36,7 @@ PHASES = ("forward", "backward", "optimizer", "other")
 TOP_N = 10
 
 _Span = Tuple[str, float, float]
+_TRANSFORM = re.compile(r"^[\w.-]+\((.*)\)$")
 
 
 # -- the wire reader ----------------------------------------------------------
@@ -141,8 +148,20 @@ def phase_of(name_stack: str) -> str:
     return "other"
 
 
+def scopes_of(name_stack: str) -> Tuple[frozenset, bool]:
+    """The scope names of an operation's name stack, and whether it runs
+    in the backward pass (under ``transpose(…)``)."""
+    names, backward = set(), False
+    for part in (name_stack or "").rstrip(":").split("/")[:-1]:
+        backward |= part.startswith("transpose(")
+        while (m := _TRANSFORM.match(part)) is not None:
+            part = m.group(1)
+        names.add(part)
+    return frozenset(names), backward
+
+
 def load(path: Path) -> Dict:
-    """``{"devices": {plane: [(op, start_ns, end_ns, phase)]}, "host":
+    """``{"devices": {plane: [(op, start_ns, end_ns, name stack)]}, "host":
     [(name, start_ns, end_ns)], "args": {span name: [stats, ...]}}``:
     host events are the window, the harness spans and every
     ``traceml/`` span, on any thread."""
@@ -161,7 +180,7 @@ def load(path: Path) -> Dict:
                 if line.name == tr.OPS_LINE:
                     ops.extend(
                         (tr.op_name(ev.name), ev.start_ns, ev.start_ns + ev.duration_ns,
-                         phase_of(stack.get(ev.name)))
+                         stack.get(ev.name))
                         for ev in line.events
                     )
             continue
@@ -197,8 +216,11 @@ def _attribute(gaps: List[Tuple[float, float]], spans: List[_Span]) -> List[str]
 
 def reduce(events: Dict) -> Dict:
     """Per chip, averaged over chips, inside the window: seconds of
-    device operations by phase (``phase_s``) and in all (``ops_s``), the
-    heaviest ``other`` operations, idle seconds and the share of them
+    device operations by phase (``phase_s``) and in all (``ops_s``), by
+    scope name split into the forward and the backward pass (``scope_s``:
+    ``{name: {"fwd": s, "bwd": s}}``, an operation counted once under
+    each name in its stack), the heaviest ``other`` operations, idle
+    seconds and the share of them
     that a ``traceml/`` span overlaps, and the idle time by the span
     each gap goes to.  Host side: each ``traceml/`` span's count and
     seconds inside the window (``spans``)."""
@@ -213,15 +235,22 @@ def reduce(events: Dict) -> Dict:
     harness = [s for s in events["host"] if s[0] in tr.HOST_SPANS]
     ours_union = tr.union(((a, b) for _, a, b in ours), lo, hi)
     phase_ns: Dict[str, float] = {p: 0.0 for p in PHASES}
+    scope_ns: Dict[str, Dict[str, float]] = defaultdict(lambda: {"fwd": 0.0, "bwd": 0.0})
+    parsed: Dict[str, tuple] = {}
     other_ns: Dict[str, float] = defaultdict(float)
     gap_ns: Dict[str, float] = defaultdict(float)
     idle_ns = covered_ns = 0.0
     for ops in devices.values():
-        for name, a, b, phase in ops:
+        for name, a, b, stack in ops:
+            if stack not in parsed:
+                parsed[stack] = (phase_of(stack), *scopes_of(stack))
+            phase, scopes, backward = parsed[stack]
             ns = tr._overlap((a, b), (lo, hi))
             phase_ns[phase] += ns
             if phase == "other":
                 other_ns[name] += ns
+            for scope in scopes:
+                scope_ns[scope]["bwd" if backward else "fwd"] += ns
         idle = tr.gaps(tr.union(((a, b) for _, a, b, _ in ops), lo, hi), lo, hi)
         idle_ns += sum(b - a for a, b in idle)
         covered_ns += sum(tr._overlap(u, g) for u, g in _pairs(ours_union, idle))
@@ -242,6 +271,9 @@ def reduce(events: Dict) -> Dict:
     return {
         "phase_s": {p: v / n / 1e9 for p, v in phase_ns.items()},
         "ops_s": sum(phase_ns.values()) / n / 1e9,
+        "scope_s": {
+            k: {side: ns / n / 1e9 for side, ns in v.items()} for k, v in sorted(scope_ns.items())
+        },
         "other_ops": top(other_ns),
         "idle_s": idle_ns / n / 1e9,
         "idle_overlap_pct": 100.0 * covered_ns / idle_ns if idle_ns else 0.0,

@@ -9,11 +9,12 @@ from pathlib import Path
 import jax
 import pytest
 
-from benchmark import check, reference
+from benchmark import check, discovery, reference
 from benchmark.weights import seed_key, token_pool
 
 SPEC = json.loads((Path(__file__).parent / "tiny_cell.json").read_text())
 M, LOAD = SPEC["model"], SPEC["load"]
+FAMILY = discovery.load_family(M["family"]).reference
 LIMITS = SPEC["cell"]["limits"]
 SEEDS = (11, 2**31 + 3, 2**34 + 9)
 
@@ -21,7 +22,7 @@ SEEDS = (11, 2**31 + 3, 2**34 + 9)
 def _readings(seed, variant, n_devices=1):
     toks = token_pool(seed, 3, LOAD["batch"], LOAD["seq"], M["vocab_size"])
     return reference.readings(
-        M, M["optimizer"], M["init_std"], seed_key(seed), toks, variant,
+        FAMILY, M, M["optimizer"], M["init_std"], seed_key(seed), toks, variant,
         jax.devices()[:n_devices],
     )
 

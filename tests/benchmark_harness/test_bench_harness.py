@@ -8,9 +8,11 @@ from pathlib import Path
 import jax
 import pytest
 
-from benchmark import harness
+from benchmark import discovery, harness
 
-SPEC = json.loads((Path(__file__).parent / "tiny_cell.json").read_text())
+TINY = json.loads((Path(__file__).parent / "tiny_cell.json").read_text())
+SPEC = dict(TINY, family=discovery.load_family(TINY["model"]["family"]))
+PROGRAM = SPEC["family"].program
 SPEC4 = dict(SPEC, chips=4, cell=dict(SPEC["cell"], mesh={"data": 1, "fsdp": 2, "tensor": 2}))
 SEED = 2**33 + 5
 #: weights the program splits over fsdp along dim 1 (models/transformer.py)
@@ -18,7 +20,7 @@ ROW_PARALLEL = ("wo", "w_down")
 
 
 def _broken_step(kind):
-    real = harness.make_train_step
+    real = PROGRAM.make_train_step
 
     def make(model, tx, mesh=None):
         step = real(model, tx, mesh=mesh)
@@ -88,13 +90,13 @@ def test_sound_run_is_correct(spec):
 
 @pytest.mark.parametrize("kind", ["state_unchanged", "half_batch", "token_altered"])
 def test_broken_step_is_not_correct(monkeypatch, kind):
-    monkeypatch.setattr(harness, "make_train_step", _broken_step(kind))
+    monkeypatch.setattr(PROGRAM, "make_train_step", _broken_step(kind))
     result, lines = _run()
     assert result["correct"] is False, lines
 
 
 def test_exchange_left_out_is_not_correct(monkeypatch):
-    monkeypatch.setattr(harness, "make_train_step", _no_exchange_step)
+    monkeypatch.setattr(PROGRAM, "make_train_step", _no_exchange_step)
     result, lines = _run(SPEC4)
     assert result["device"]["count"] == 4
     assert result["correct"] is False, lines

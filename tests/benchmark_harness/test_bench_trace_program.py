@@ -1,7 +1,8 @@
 """``trace_program``: name stacks read from a trace's event metadata,
-phases from them, and the reduction — phase sums, the idle time a
-``traceml/`` span overlaps, and gaps given to the program's spans before
-the harness's — on synthetic events, on a TPU v5e trace recorded before
+phases and scopes from them, and the reduction — phase and scope sums,
+the idle time a ``traceml/`` span overlaps, and gaps given to the
+program's spans before the harness's — on synthetic events, on a TPU v5e
+trace recorded before
 the scopes and spans existed (``small.xplane.pb``) and on one recorded
 with them (``scoped.xplane.pb``: 4 annotated steps of the tiny cell,
 0.12 s of host sleep with the runtime ticking every 0.05 s, 2 more
@@ -11,6 +12,7 @@ from pathlib import Path
 
 import pytest
 
+from benchmark import discovery
 from benchmark import trace_program as tp
 from benchmark import trace_reduce as tr
 
@@ -35,12 +37,36 @@ def test_phase_of(stack, phase):
     assert tp.phase_of(stack) == phase
 
 
+@pytest.mark.parametrize(
+    "stack,scopes,backward",
+    [
+        ("jit(train_step)/jvp(forward)/DecoderLM/layer_0/attn/wq/dot_general:",
+         {"train_step", "forward", "DecoderLM", "layer_0", "attn", "wq"}, False),
+        ("jit(train_step)/transpose(jvp(forward))/DecoderLM/layer_1/attn/attn._attend"
+         "/bhqk,bkhd->bqhd/dot_general:",
+         {"train_step", "forward", "DecoderLM", "layer_1", "attn", "attn._attend",
+          "bhqk,bkhd->bqhd"}, True),
+        ("jit(train_step)/optimizer/mul", {"train_step", "optimizer"}, False),
+        ("dot_general:", set(), False),
+        ("", set(), False),
+        (None, set(), False),
+    ],
+)
+def test_scopes_of(stack, scopes, backward):
+    assert tp.scopes_of(stack) == (scopes, backward)
+
+
+FWD = "jit(s)/jvp(forward)/attn/dot:"
+BWD = "jit(s)/transpose(jvp(forward))/attn/dot:"
+OPT = "jit(s)/optimizer/mul:"
+
+
 def _events():
     return {
         "devices": {
-            "/device:TPU:0": [("f", 10, 40, "forward"), ("b", 40, 70, "backward"),
-                              ("o", 80, 90, "optimizer"), ("c", 90, 95, "other")],
-            "/device:TPU:1": [("f", 10, 90, "forward")],
+            "/device:TPU:0": [("f", 10, 40, FWD), ("b", 40, 70, BWD),
+                              ("o", 80, 90, OPT), ("c", 90, 95, None)],
+            "/device:TPU:1": [("f", 10, 90, FWD)],
         },
         "host": [
             (tr.WINDOW, 0, 100), ("dispatch", 0, 12), ("wait_loss", 70, 100),
@@ -56,6 +82,13 @@ def test_reduce_synthetic():
         {"forward": 55e-9, "backward": 15e-9, "optimizer": 5e-9, "other": 2.5e-9}
     )
     assert r["ops_s"] == pytest.approx(77.5e-9)
+    # an operation counts once under each scope in its stack
+    assert r["scope_s"] == {
+        "s": {"fwd": pytest.approx(60e-9), "bwd": pytest.approx(15e-9)},
+        "forward": {"fwd": pytest.approx(55e-9), "bwd": pytest.approx(15e-9)},
+        "attn": {"fwd": pytest.approx(55e-9), "bwd": pytest.approx(15e-9)},
+        "optimizer": {"fwd": pytest.approx(5e-9), "bwd": 0.0},
+    }
     assert r["other_ops"] == [["c", pytest.approx(2.5e-9)]]
     # idle: dev0 [0,10] [70,80] [95,100]; dev1 [0,10] [90,100] -> 45 ns
     assert r["idle_s"] == pytest.approx(22.5e-9)
@@ -132,3 +165,43 @@ def test_scoped_chip_trace():
     # takes it, where the harness spans alone gave it to ``dispatch``
     assert max(gaps, key=gaps.get) == "traceml/sampler_tick"
     assert max(base["idle_gaps"], key=lambda g: g[1])[0] == "dispatch"
+
+
+STEPS = 6  # the steps of the scoped trace's window
+
+
+def _phases(rec):
+    return {ph: discovery.reader(f"phase.{ph}_ms")(rec) for ph in ("fwd", "bwd", "opt")}
+
+
+def test_scope_table_of_the_scoped_chip_trace():
+    """``scope_s`` on the tiny cell's TPU v5e trace: the train step's
+    scopes hold every named operation, the forward scope's two passes
+    and the optimizer scope are the phases, and the phase readers give
+    them in ms a step."""
+    r = tp.reduce(tp.load(SCOPED))
+    s, ph = r["scope_s"], r["phase_s"]
+    assert s["forward"]["fwd"] == pytest.approx(ph["forward"], rel=1e-12)
+    assert s["forward"]["bwd"] == pytest.approx(ph["backward"], rel=1e-12)
+    assert s["optimizer"] == {"fwd": pytest.approx(ph["optimizer"], rel=1e-12), "bwd": 0.0}
+    step = s["train_step"]
+    assert step["fwd"] + step["bwd"] == pytest.approx(ph["forward"] + ph["backward"]
+                                                      + ph["optimizer"], rel=1e-9)
+    assert s["DecoderLM"]["fwd"] < s["forward"]["fwd"]
+    assert {"layer_0", "layer_1", "attn", "mlp", "wq", "w_down"} <= set(s)
+    got = _phases({"trace": {"program": r, "steps": STEPS}})
+    assert got == {
+        "fwd": pytest.approx(1e3 * ph["forward"] / STEPS, rel=1e-12),
+        "bwd": pytest.approx(1e3 * ph["backward"] / STEPS, rel=1e-12),
+        "opt": pytest.approx(1e3 * ph["optimizer"] / STEPS, rel=1e-12),
+    }
+    assert all(v > 0 for v in got.values())
+
+
+def test_phase_readers_read_nothing_without_scopes():
+    """An untraced run, and a trace of a program without the scopes
+    (``small.xplane.pb``), give no phase."""
+    assert _phases({}) == {"fwd": None, "bwd": None, "opt": None}
+    r = tp.reduce(tp.load(SMALL))
+    assert "forward" not in r["scope_s"] and "optimizer" not in r["scope_s"]
+    assert _phases({"trace": {"program": r, "steps": 3}}) == {"fwd": None, "bwd": None, "opt": None}

@@ -49,35 +49,67 @@ def topo(no_compile_cache):
 
 
 def _compile(spec, devices):
-    """The attached step's program (``wrap_step_fn`` jits exactly this)."""
-    from benchmark.harness import model_config
-    from traceml_tpu.models import init_train_state, make_train_step, param_shardings
+    """The attached step's program (``wrap_step_fn`` jits exactly this),
+    built by the cell's family as the harness builds it, from shapes. On
+    a mesh ``eval_shape`` keeps no shardings: the compiler takes the
+    state's layout from the step's own sharding constraints (the same
+    bytes per device as ``param_shardings`` gives, to 0.01%)."""
     from traceml_tpu.parallel.mesh import batch_sharding, make_mesh
 
-    cfg = model_config(spec)
+    mesh = make_mesh(spec["cell"]["mesh"], devices=devices) if spec["cell"].get("mesh") else None
     made = {}
 
     def init(key):
-        made["model"], state, made["tx"] = init_train_state(
-            cfg, key, learning_rate=spec["model"]["optimizer"]["learning_rate"]
-        )
+        made["step"], state = spec["family"].program.build(spec, key, mesh)
         return state
 
     shapes = jax.eval_shape(init, jax.random.PRNGKey(0))
-    mesh = None
-    if spec["cell"].get("mesh"):
-        mesh = make_mesh(spec["cell"]["mesh"], devices=devices)
-        state_sh, tok_sh = param_shardings(shapes, mesh), batch_sharding(mesh)
-    else:
+    if mesh is None:
         one = SingleDeviceSharding(devices[0])
-        state_sh, tok_sh = jax.tree.map(lambda _: one, shapes), one
-    state = jax.tree.map(
-        lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh), shapes, state_sh
-    )
+        shapes, tok_sh = jax.tree.map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one), shapes
+        ), one
+    else:
+        tok_sh = batch_sharding(mesh)
     load = spec["load"]
     tokens = jax.ShapeDtypeStruct((load["batch"], load["seq"]), jnp.int32, sharding=tok_sh)
-    step = jax.jit(make_train_step(made["model"], made["tx"], mesh=mesh), donate_argnums=(0,))
-    return step.lower(state, tokens).compile()
+    step = jax.jit(made["step"], donate_argnums=(0,))
+    return step.lower(shapes, tokens).compile()
+
+
+def _total(mem) -> int:
+    return (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_cell_reference_step_compiles_and_fits(topo, cell):
+    """The float32 reference's step at the cell's size and chips, as a
+    run's check builds it once the program is freed: it fits the HBM,
+    and its code is one scanned layer's, not one copy per layer (phi's
+    reference with its layers unrolled compiled to 878 MB of code)."""
+    from benchmark import reference
+    from benchmark.discovery import load_cell
+    from benchmark.peaks import peaks_for
+
+    spec = load_cell(cell)
+    m, load = spec["model"], spec["load"]
+    devices = tuple(topo.devices)[: spec["chips"]]
+    init, step, _, _, tok_sh = reference._programs(
+        spec["family"].reference, json.dumps(m, sort_keys=True),
+        tuple(sorted(m["optimizer"].items())), float(m["init_std"]), "f32", devices,
+    )
+    state = jax.eval_shape(init, jax.random.key(0))
+    t_sh = None
+    if tok_sh is None:  # one chip: the step's arguments say which
+        tok_sh = t_sh = SingleDeviceSharding(devices[0])
+        state = jax.tree.map(lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=t_sh), state)
+    tokens = jax.ShapeDtypeStruct((load["batch"], load["seq"]), jnp.int32, sharding=tok_sh)
+    t = jax.ShapeDtypeStruct((), jnp.float32, sharding=t_sh)
+    mem = step.lower(*state, tokens, t).compile().memory_analysis()
+    print(f"{cell} reference: {_total(mem) / 1e9:.3f} GB, code {mem.generated_code_size_in_bytes / 1e6:.1f} MB")
+    assert _total(mem) <= peaks_for(devices[0].device_kind)["hbm_bytes"]
+    assert mem.generated_code_size_in_bytes < 200e6
 
 
 @pytest.mark.parametrize("cell", CELLS)
@@ -89,8 +121,7 @@ def test_cell_step_compiles_and_fits(topo, cell):
     devices = list(topo.devices)[: spec["chips"]]
     compiled = _compile(spec, devices)
     mem = compiled.memory_analysis()
-    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
-             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    total = _total(mem)
     hbm = peaks_for(devices[0].device_kind)["hbm_bytes"]
     print(f"{cell}: {total / 1e9:.3f} GB of {hbm / 1e9:.0f} GB ({mem})")
     assert total <= hbm
