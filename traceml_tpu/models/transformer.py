@@ -14,6 +14,12 @@ Design notes (why it looks the way it does):
   HBM, the standard long-context lever.
 * **GQA + RoPE + RMSNorm + SwiGLU**: the contemporary decoder recipe,
   kept minimal and readable.
+* **Latent attention and routed experts** (``ModelConfig.mla`` /
+  ``.moe``): DeepSeek-V3-style layers (``LatentAttention`` here,
+  ``models/moe.py``'s routed expert layer) stacked after
+  ``moe.first_dense`` dense layers; such a model also returns its
+  balance loss and per-expert token counts, which ``make_train_step``
+  adds to the loss and returns as the counter ``expert_load``.
 """
 
 from __future__ import annotations
@@ -24,6 +30,42 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import flax.linen as nn
+
+
+@dataclasses.dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2/V3): keys and values
+    come from one ``kv_lora_rank``-wide latent; q and k are a
+    ``qk_nope_head_dim`` part and a ``qk_rope_head_dim`` RoPE part, the
+    latter's key shared by all heads; values are ``v_head_dim`` wide."""
+
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+
+
+@dataclasses.dataclass(frozen=True)
+class ExpertConfig:
+    """Routed experts after ``first_dense`` dense layers (DeepSeek-V3
+    ``noaux_tc`` routing with one group): sigmoid scores over all
+    ``n_experts``, ``top_k`` chosen on score + a correction bias,
+    weighted by their scores normalised over the chosen and scaled by
+    ``routed_scale``, plus a shared SwiGLU ``n_shared`` experts wide.
+    This layer holds experts ``[expert_offset, expert_offset + n_held)``
+    and computes their part of the result only (expert parallelism: the
+    other experts' part comes from the chips that hold them).
+    ``aux_alpha`` weighs the sequence-wise balance loss."""
+
+    n_experts: int = 64
+    top_k: int = 6
+    expert_ffn: int = 1408
+    n_shared: int = 2
+    first_dense: int = 1
+    routed_scale: float = 1.0
+    expert_offset: int = 0
+    n_held: int = 64
+    aux_alpha: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -48,6 +90,9 @@ class ModelConfig:
     attention_impl: str = "dense"
     context_axis: Any = None     # mesh axis name, e.g. "context"
     mesh: Any = None             # jax.sharding.Mesh (shard_map needs it)
+    rms_eps: float = 1e-6
+    mla: Optional[MLAConfig] = None     # latent attention in every layer
+    moe: Optional[ExpertConfig] = None  # routed experts after moe.first_dense
 
     @property
     def head_dim(self) -> int:
@@ -235,20 +280,77 @@ class Attention(nn.Module):
         )(q, k, v)
 
 
+class LatentAttention(nn.Module):
+    """Multi-head latent attention (DeepSeek-V2/V3, ``q_lora_rank``
+    null): q from ``q_proj``; ``kv_a_proj_with_mqa`` gives the latent
+    and one RoPE key shared by all heads; the latent, RMS-normed, gives
+    each head's non-RoPE key and its value through ``kv_b_proj``.  RoPE
+    follows the published checkpoint layout: the RoPE parts are
+    de-interleaved (even features, then odd) before rotate-half.  Scores
+    are scaled by 1/sqrt(q width); q/k and v widths differ, so the dense
+    causal path computes them."""
+
+    cfg: ModelConfig
+
+    @nn.compact
+    def __call__(self, x, positions):
+        cfg, m = self.cfg, self.cfg.mla
+        B, S, H = x.shape
+        nh, dn, dr, dv = cfg.n_heads, m.qk_nope_head_dim, m.qk_rope_head_dim, m.v_head_dim
+
+        def dense(width, name):
+            return nn.Dense(width, use_bias=False, dtype=cfg.dtype,
+                            param_dtype=cfg.param_dtype, name=name)
+
+        with jax.named_scope("mla"):
+            q = dense(nh * (dn + dr), "q_proj")(x).reshape(B, S, nh, dn + dr)
+            kv = dense(m.kv_lora_rank + dr, "kv_a_proj_with_mqa")(x)
+            latent = RMSNorm(eps=cfg.rms_eps, dtype=cfg.dtype, name="kv_a_layernorm")(
+                kv[..., : m.kv_lora_rank]
+            )
+            k_pe = _rope(_deinterleave(kv[..., None, m.kv_lora_rank:]), positions,
+                         cfg.rope_theta)
+            kv = dense(nh * (dn + dv), "kv_b_proj")(latent).reshape(B, S, nh, dn + dv)
+            q = jnp.concatenate(
+                [q[..., :dn], _rope(_deinterleave(q[..., dn:]), positions, cfg.rope_theta)],
+                axis=-1,
+            )
+            k = jnp.concatenate(
+                [kv[..., :dn], jnp.broadcast_to(k_pe, (B, S, nh, dr))], axis=-1
+            )
+            from traceml_tpu.ops.attention import causal_attention
+
+            out = causal_attention(q, k, kv[..., dn:]).reshape(B, S, nh * dv)
+            return dense(H, "o_proj")(out)
+
+
+def _deinterleave(x: jnp.ndarray) -> jnp.ndarray:
+    """Features (0, 2, 4, …, 1, 3, 5, …) of the last dim: the published
+    DeepSeek checkpoints store RoPE pairs interleaved."""
+    return jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
+
+
 class MLP(nn.Module):
     cfg: ModelConfig
+    width: Optional[int] = None  # None: cfg.ffn_hidden
 
     @nn.compact
     def __call__(self, x):
         cfg = self.cfg
-        gate = nn.Dense(cfg.ffn_hidden, use_bias=False, dtype=cfg.dtype,
+        width = cfg.ffn_hidden if self.width is None else self.width
+        gate = nn.Dense(width, use_bias=False, dtype=cfg.dtype,
                         param_dtype=cfg.param_dtype, name="w_gate")(x)
-        up = nn.Dense(cfg.ffn_hidden, use_bias=False, dtype=cfg.dtype,
+        up = nn.Dense(width, use_bias=False, dtype=cfg.dtype,
                       param_dtype=cfg.param_dtype, name="w_up")(x)
         return nn.Dense(cfg.hidden, use_bias=False, dtype=cfg.dtype,
                         param_dtype=cfg.param_dtype, name="w_down")(
             nn.silu(gate) * up
         )
+
+
+def attention_for(cfg: ModelConfig):
+    """The layer's attention module class: latent or multi-head."""
+    return Attention if cfg.mla is None else LatentAttention
 
 
 class Block(nn.Module):
@@ -257,16 +359,22 @@ class Block(nn.Module):
     @nn.compact
     def __call__(self, x, positions):
         cfg = self.cfg
-        x = x + Attention(cfg, name="attn")(
-            RMSNorm(dtype=cfg.dtype, name="attn_norm")(x), positions
+        x = x + attention_for(cfg)(cfg, name="attn")(
+            RMSNorm(eps=cfg.rms_eps, dtype=cfg.dtype, name="attn_norm")(x), positions
         )
         x = x + MLP(cfg, name="mlp")(
-            RMSNorm(dtype=cfg.dtype, name="mlp_norm")(x)
+            RMSNorm(eps=cfg.rms_eps, dtype=cfg.dtype, name="mlp_norm")(x)
         )
         return x
 
 
 class DecoderLM(nn.Module):
+    """Logits over the vocabulary; with ``cfg.moe``, layers from
+    ``moe.first_dense`` on are routed-expert layers, and the model
+    returns ``(logits, aux_loss, expert_load)``: the balance losses
+    summed over those layers, and each one's tokens per expert, int32
+    (expert layers, ``moe.n_experts``)."""
+
     cfg: ModelConfig
 
     @nn.compact
@@ -279,12 +387,24 @@ class DecoderLM(nn.Module):
         block = Block
         if cfg.remat:
             block = nn.remat(Block, static_argnums=())
-        for i in range(cfg.n_layers):
+        first_expert = cfg.n_layers if cfg.moe is None else cfg.moe.first_dense
+        for i in range(first_expert):
             x = block(cfg, name=f"layer_{i}")(x, positions)
-        x = RMSNorm(dtype=cfg.dtype, name="final_norm")(x)
+        aux, loads = jnp.float32(0.0), []
+        if cfg.moe is not None:
+            from traceml_tpu.models.moe import ExpertBlock
+
+            expert_block = nn.remat(ExpertBlock) if cfg.remat else ExpertBlock
+            for i in range(first_expert, cfg.n_layers):
+                x, layer_aux, load = expert_block(cfg, name=f"layer_{i}")(x, positions)
+                aux = aux + layer_aux
+                loads.append(load)
+        x = RMSNorm(eps=cfg.rms_eps, dtype=cfg.dtype, name="final_norm")(x)
         logits = nn.Dense(cfg.vocab_size, use_bias=False, dtype=jnp.float32,
                           param_dtype=cfg.param_dtype, name="lm_head")(x)
-        return logits
+        if cfg.moe is None:
+            return logits
+        return logits, aux, jnp.stack(loads)
 
 
 # -- sharding ------------------------------------------------------------
@@ -333,13 +453,25 @@ def param_shardings(params, mesh) -> Any:
 # -- training ------------------------------------------------------------
 
 
-def loss_fn(params, apply_fn, tokens) -> jnp.ndarray:
-    """Next-token cross entropy (inputs=tokens[:, :-1], targets=[:, 1:])."""
-    logits = apply_fn({"params": params}, tokens[:, :-1])
+def loss_and_counters(params, apply_fn, tokens) -> Tuple[jnp.ndarray, Dict[str, Any]]:
+    """Next-token cross entropy (inputs=tokens[:, :-1], targets=[:, 1:]),
+    plus the model's auxiliary loss where it has one; and the model's
+    counters (``expert_load`` for a routed-expert model, else none)."""
+    out = apply_fn({"params": params}, tokens[:, :-1])
+    logits, aux, counters = out, None, {}
+    if isinstance(out, tuple):
+        logits, aux, load = out
+        counters = {"expert_load": load}
     targets = tokens[:, 1:]
     logp = jax.nn.log_softmax(logits, axis=-1)
     nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
-    return nll.mean()
+    loss = nll.mean() if aux is None else nll.mean() + aux
+    return loss, counters
+
+
+def loss_fn(params, apply_fn, tokens) -> jnp.ndarray:
+    """The training loss alone (see :func:`loss_and_counters`)."""
+    return loss_and_counters(params, apply_fn, tokens)[0]
 
 
 def init_train_state(
@@ -380,6 +512,11 @@ def init_train_state(
 def make_train_step(model: DecoderLM, tx, mesh=None) -> Any:
     """The (un-jitted) functional train step: (state, tokens) → (state, metrics).
 
+    ``metrics`` holds ``loss`` (with the model's auxiliary loss, if any)
+    and, for a model that has them, ``counters``: ``expert_load`` for a
+    routed-expert model (``wrap_step_fn`` keeps each in a ring of its
+    name, ``TraceState.counter_rings``).
+
     Callers wrap it with ``traceml_tpu.wrap_step_fn`` (tracing + AOT
     compile attribution) or plain ``jax.jit``; donate state for in-place
     updates.  The loss runs in a ``forward`` name scope, so its
@@ -395,10 +532,10 @@ def make_train_step(model: DecoderLM, tx, mesh=None) -> Any:
 
     def forward(params, apply, tokens):
         with jax.named_scope("forward"):
-            return loss_fn(params, apply, tokens)
+            return loss_and_counters(params, apply, tokens)
 
     def train_step(state, tokens):
-        loss, grads = jax.value_and_grad(forward)(
+        (loss, counters), grads = jax.value_and_grad(forward, has_aux=True)(
             state["params"], model.apply, tokens
         )
         with jax.named_scope("optimizer"):
@@ -415,6 +552,8 @@ def make_train_step(model: DecoderLM, tx, mesh=None) -> Any:
             new_state = jax.lax.with_sharding_constraint(
                 new_state, param_shardings(new_state, mesh)
             )
-        return new_state, {"loss": loss}
+        if not counters:
+            return new_state, {"loss": loss}
+        return new_state, {"loss": loss, "counters": counters}
 
     return train_step

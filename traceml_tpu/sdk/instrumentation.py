@@ -80,6 +80,7 @@ class trace_step:
         # background resolver instead.
         if gov.allow_inline_sweep():
             get_marker_resolver().sweep_inline()
+        st.drain_counters(time.monotonic())
         st.sample_markers = gov.begin_step()
         st.tls.in_step = True
         self._step = st.begin_step()

@@ -10,7 +10,10 @@ RLock-guarded; the hot-path reads are plain attribute loads on a
 from __future__ import annotations
 
 import threading
-from typing import Any, Callable, List, Optional
+from collections import deque
+from typing import Any, Callable, Dict, List, Optional
+
+import numpy as np
 
 from traceml_tpu.utils.self_trace import TimedRing
 from traceml_tpu.utils.step_memory import StepMemoryTracker
@@ -61,6 +64,12 @@ class TraceState:
         # the wait from the step's flush to its row (sampler tick + the
         # FIFO hold until its device markers resolve)
         self.emit_waits = TimedRing(8192)
+        # the program's own counters: each array under the ``counters``
+        # dict of a step's metrics, as (host read time, ndarray) in the
+        # ring of its name; held from dispatch until the step resolves,
+        # then read without blocking (note_counters / drain_counters)
+        self.counter_rings: Dict[str, TimedRing] = {}
+        self._counters_pending: "deque[Dict[str, Any]]" = deque(maxlen=64)
         # wall-clock of the previous trace_step exit: successive steps
         # tile the wall clock, so inter-step host time (input fetch in the
         # idiomatic `for batch in loader: with trace_step():` pattern) is
@@ -111,6 +120,36 @@ class TraceState:
         self.host_ns += total
         self.host_steps += 1
         self.host_ns_ring.add(now, total)
+
+    def note_counters(self, counters: Dict[str, Any]) -> None:
+        """Hold a dispatched step's counters and start their copies to
+        the host (training thread)."""
+        for value in counters.values():
+            try:
+                value.copy_to_host_async()
+            except Exception:
+                pass
+        self._counters_pending.append(counters)
+
+    def drain_counters(self, now: float) -> None:
+        """Read the held counters whose steps have resolved into
+        ``counter_rings``, oldest first, stopping at the first step still
+        in flight: never a blocking read (training thread, at each
+        step's enter)."""
+        pending = self._counters_pending
+        while pending:
+            try:
+                if not all(v.is_ready() for v in pending[0].values()):
+                    return
+                values = {k: np.asarray(v) for k, v in pending[0].items()}
+            except Exception:  # a deleted buffer: nothing to read
+                values = {}
+            pending.popleft()
+            for name, value in values.items():
+                ring = self.counter_rings.get(name)
+                if ring is None:
+                    ring = self.counter_rings[name] = TimedRing(4096)
+                ring.add(now, value)
 
     def tracer_stats(self) -> dict:
         """Host time per step and the emission waits, for the

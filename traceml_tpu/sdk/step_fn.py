@@ -18,7 +18,10 @@ compiles outside the wrapped function.
 The wrapper's own host work outside the jitted call (picking the
 marker handles, the envelope hand-off) runs in a ``traceml/wrap``
 profiler span and counts toward the step's tracer cost on
-``TraceState``.
+``TraceState``.  A step whose metrics carry a ``counters`` dict (the
+program's own counts, e.g. ``make_train_step``'s ``expert_load``) hands
+it to ``TraceState.note_counters``, which reads each array to the host
+once its step has resolved (``TraceState.counter_rings``).
 
 Fail-open: the wrapper never raises on its own behalf; user errors
 propagate untouched.
@@ -57,6 +60,16 @@ def _path_getter(path) -> Optional[Callable[[Any], Any]]:
         return obj
 
     return get
+
+
+def _counters(out) -> Optional[dict]:
+    """The ``counters`` dict of a ``(state, metrics)`` step output, or
+    None."""
+    if isinstance(out, tuple) and len(out) == 2 and isinstance(out[1], dict):
+        counters = out[1].get("counters")
+        if isinstance(counters, dict) and counters:
+            return counters
+    return None
 
 
 class WrappedStepFn:
@@ -104,9 +117,12 @@ class WrappedStepFn:
         # a ~35-leaf train state costs ~130 µs per call while the
         # dispatch is in flight (it contends with the backend's compute
         # threads) — ~1% of a 12 ms step; a few dict/tuple lookups cost
-        # ~1 µs.  Falls back to the flatten path when the structure
-        # changes or the path hits a non-array.
-        self._leaf_getter: Optional[Callable[[Any], Any]] = None
+        # ~1 µs.  Kept as (output treedef, getter, jit cache size): an
+        # output's treedef changes only with a retrace, which grows the
+        # jitted callable's cache, so a grown cache (or, for a callable
+        # without one, a different treedef) sends the call back through
+        # the flatten path, as does a path that hits a non-array.
+        self._leaf_getter: Optional[Tuple[Any, Callable[[Any], Any], Optional[int]]] = None
 
     @property
     def compile_count(self) -> int:
@@ -114,23 +130,35 @@ class WrappedStepFn:
         created (a superset of this function's own compiles)."""
         return get_state().compile_events_seen - self._compiles_at_start
 
+    def _traces(self) -> Optional[int]:
+        """Entries in the jitted callable's cache, or None without one."""
+        try:
+            return self._jfn._cache_size()
+        except Exception:
+            return None
+
     def _pick_handles(self, out):
         """Smallest ready-able output leaf, extracted on the steady path
         by a cached key-path getter (NO per-call tree_flatten — see
         ``_leaf_getter``); the selection policy itself lives in
         timing.smallest_ready_index."""
-        getter = self._leaf_getter
-        if getter is not None:
-            try:
-                leaf = getter(out)
-                if hasattr(leaf, "is_ready"):
-                    return [leaf]
-            except Exception:
-                pass
+        import jax
+
+        cached = self._leaf_getter
+        if cached is not None:
+            treedef, getter, traces = cached
+            now = self._traces()
+            if (now == traces) if now is not None else (
+                jax.tree_util.tree_structure(out) == treedef
+            ):
+                try:
+                    leaf = getter(out)
+                    if hasattr(leaf, "is_ready"):
+                        return [leaf]
+                except Exception:
+                    pass
             self._leaf_getter = None  # structure changed: rebuild below
         try:
-            import jax
-
             from traceml_tpu.utils.timing import smallest_ready_index
 
             flat, treedef = jax.tree_util.tree_flatten_with_path(out)
@@ -147,7 +175,9 @@ class WrappedStepFn:
                 if len(self._leaf_idx) > 64:
                     self._leaf_idx.clear()
                 self._leaf_idx[treedef] = idx
-            self._leaf_getter = _path_getter(flat[idx][0])
+            getter = _path_getter(flat[idx][0])
+            if getter is not None:
+                self._leaf_getter = (treedef, getter, self._traces())
             return [leaves[idx]]
         except Exception:
             return []
@@ -240,6 +270,10 @@ class WrappedStepFn:
                     marker.step_end_hint = st.tls.in_step
                     tr.event.marker = marker
             region.__exit__(None, None, None)
+            if st.tls.in_step:
+                counters = _counters(out)
+                if counters is not None:
+                    st.note_counters(counters)
             # envelope hand-off + dispatch-time resolver submission (the
             # fine-cadence stamping that intra-step device edges need) —
             # see publish_region_marker's docstring
