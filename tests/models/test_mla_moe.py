@@ -269,3 +269,53 @@ def test_cell_config_is_the_catalog_entry_cut():
     pcfg = FAMILY.program.model_config({"model": m, "load": {"seq": 2048}})
     assert (pcfg.moe.n_experts, pcfg.moe.n_held, pcfg.ffn_hidden) == (64, 8, 11264)
     assert pcfg.remat and pcfg.rms_eps == 1e-5
+
+
+def _first_step(model, tx, state, mesh=None):
+    """One train step: which path each latent attention call took, the
+    loss, and the first gradient (Adam's first moment ÷ (1 − b1))."""
+    from traceml_tpu.ops.attention import record_latent_dispatch
+
+    with record_latent_dispatch() as calls:
+        new, m = jax.jit(make_train_step(model, tx, mesh=mesh))(state, _tokens())
+    grads = jax.tree.map(lambda mu: mu / (1 - CFG["optimizer"]["b1"]),
+                         new["opt_state"][0].mu)
+    return [c[0] for c in calls], m["loss"], grads
+
+
+def test_latent_kernel_step_matches_the_dense_path(monkeypatch):
+    """The train step with latent attention on the splash kernels
+    (interpreted; from 16 tokens on here, so S = 24 pads to one block):
+    the loss and first gradients of the dense path, within the float32
+    round-off the reference tests allow."""
+    from traceml_tpu.ops import attention
+
+    model, state, tx = init_train_state(_program_cfg(), jax.random.key(0))
+    state["params"] = _params()
+    state["opt_state"] = tx.init(state["params"])
+    dense_paths, dense_loss, dense_grads = _first_step(model, tx, state)
+    monkeypatch.setattr(attention, "_PALLAS_MIN_SEQ", 16)
+    paths, loss, grads = _first_step(model, tx, state)
+    assert dense_paths == ["dense"] * 3 and paths == ["kernel"] * 3
+    np.testing.assert_allclose(loss, dense_loss, rtol=1e-5)
+    for path, g, w in zip(reference.leaf_paths(grads), jax.tree.leaves(grads),
+                          jax.tree.leaves(dense_grads)):
+        np.testing.assert_allclose(g, w, rtol=0, atol=1e-4 * float(jnp.max(jnp.abs(w))),
+                                   err_msg=path)
+
+
+def test_latent_attention_stays_dense_in_a_step_built_for_a_mesh(monkeypatch):
+    """A Pallas call is not partitioned: with a mesh, ``init_train_state``
+    and ``make_train_step`` trace latent attention on its dense path."""
+    from traceml_tpu.ops import attention
+    from traceml_tpu.parallel.mesh import make_mesh
+
+    monkeypatch.setattr(attention, "_PALLAS_MIN_SEQ", 16)
+    mesh = make_mesh({"data": 1, "fsdp": 2, "tensor": 1}, devices=jax.devices()[:2])
+    for on, want in ((mesh, "dense"), (None, "kernel")):
+        with attention.record_latent_dispatch() as calls:
+            model, state, tx = init_train_state(_program_cfg(), jax.random.key(0), mesh=on)
+        assert [c[0] for c in calls] == [want] * 3
+        with attention.record_latent_dispatch() as calls:
+            jax.eval_shape(make_train_step(model, tx, mesh=on), state, _tokens())
+        assert [c[0] for c in calls] == [want] * 3

@@ -54,3 +54,19 @@ def test_flash_kernel_compiles_for_v5e(one_chip, seq, head_dim):
         x, x, x, blk_q=BLOCK, blk_k=BLOCK, interpret=False
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_latent_kernels_compile_for_v5e(one_chip):
+    """Latent attention's splash kernels at Moonlight's cell: batch 2, 16
+    heads, S 2047 padded to 2048, q/k 192 and v 128 wide, forward and both
+    backward kernels, and no S×S buffer."""
+    from traceml_tpu.ops.attention import _splash_causal
+
+    def loss(q, k, v):
+        return jnp.sum(_splash_causal(q, k, v, interpret=False).astype(jnp.float32))
+
+    qk = jax.ShapeDtypeStruct((2, 2047, 16, 192), jnp.bfloat16, sharding=one_chip)
+    v = jax.ShapeDtypeStruct((2, 2047, 16, 128), jnp.bfloat16, sharding=one_chip)
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(qk, qk, v).compile().as_text()
+    assert text.count("tpu_custom_call") == 3
+    assert "2047,2047" not in text and "2048,2048" not in text

@@ -213,3 +213,45 @@ def test_publish_units_leaves_the_backup_throttle_to_full_ticks(tmp_path):
     pub.publish()  # the 20th full tick writes every buffered frame
     got = list(iter_backup_tables(tmp_path / "fake" / ENVELOPE_FILE))
     assert got == [("t", {"i": i}) for i in range(25)]
+
+
+class ReturningClient(CapturingClient):
+    """Down until its first redial, then records replayed bodies too."""
+
+    def __init__(self):
+        super().__init__()
+        self.up = False
+
+    def redial_now(self):
+        self.up = True
+
+    def send_batch(self, payloads):
+        return self.up and super().send_batch(payloads)
+
+    def send_encoded_body(self, body):
+        if self.up:
+            self.bodies.append(bytes(body))
+        return self.up
+
+
+def test_drain_spool_delivers_the_final_publish(tmp_path):
+    s = FakeSampler(disk_backup_dir=tmp_path / "backup")
+    client = ReturningClient()
+    pub = TelemetryPublisher(
+        [s], client, SenderIdentity(global_rank=1), spool_dir=tmp_path / "spool"
+    )
+    s.sample()
+    pub.publish(final=True, extra_payloads=[{"marker": 1}])
+    assert client.bodies == []  # the link was down: all of it spooled
+    assert pub.drain_spool(wait_s=1.0)
+    payloads, errors = msgpack_codec.decode_batch(client.bodies)
+    assert errors == 0
+    assert any(p.get("marker") == 1 for p in payloads)
+    envs = [e for e in map(normalize_telemetry_envelope, payloads) if e]
+    assert [e.tables["t"] for e in envs] == [[{"i": 0}]]
+    assert pub.stats()["transport"]["spool_frames"] == 0
+
+
+def test_drain_spool_without_a_spool_is_a_no_op(tmp_path):
+    pub = TelemetryPublisher([FakeSampler(disk_backup_dir=tmp_path)], None, SenderIdentity())
+    assert pub.drain_spool(wait_s=5.0)

@@ -272,6 +272,43 @@ def test_send_transient_never_spooled(tmp_path):
     sender.close()
 
 
+class _ComesBackClient(_FakeClient):
+    """Link double that is down until its ``up_after``-th redial."""
+
+    def __init__(self, up_after):
+        super().__init__()
+        self.ok = False
+        self.redials = 0
+        self._up_after = up_after
+
+    def redial_now(self):
+        self.redials += 1
+        self.ok = self.redials >= self._up_after
+
+
+def test_drain_before_exit_waits_for_the_link(tmp_path):
+    client = _ComesBackClient(up_after=3)
+    sender = DurableSender(client, ReplaySpool(tmp_path))
+    assert not sender.send([_enc(1), _enc(2)])
+    assert sender.drain_before_exit(wait_s=5.0, poll_s=0.01)
+    assert client.redials == 3
+    assert [p["meta"]["seq"] for p in _decode_replayed(client)] == [1, 2]
+    assert sender.stats()["spool_frames"] == 0
+    sender.close()
+
+
+def test_drain_before_exit_gives_up_after_its_wait(tmp_path):
+    client = _ComesBackClient(up_after=10**9)
+    sender = DurableSender(client, ReplaySpool(tmp_path))
+    sender.send([_enc(1)])
+    t0 = time.monotonic()
+    assert not sender.drain_before_exit(wait_s=0.2, poll_s=0.05)
+    assert time.monotonic() - t0 < 2.0
+    assert client.redials >= 2
+    assert sender.stats()["spool_frames"] == 1  # kept, never dropped
+    sender.close()
+
+
 # -- link flap through a real TCP server ---------------------------------
 
 
@@ -315,6 +352,39 @@ def test_link_flap_replay_end_to_end(tmp_path):
         drain(6)
         seqs = {p["meta"]["seq"] for p in got}
         assert set(range(6)) <= seqs, sorted(seqs)  # nothing silently lost
+    finally:
+        sender.close()
+        client.close()
+        server.stop()
+
+
+def test_exit_drain_dials_past_the_backoff(tmp_path):
+    """A rank that finishes while its aggregator is down and then comes
+    back on the same port: its last sends sit in the spool behind a
+    long backoff window, and the exit drain delivers them anyway."""
+    server = TCPServer()
+    server.start()
+    port = server.port
+    client = TCPClient("127.0.0.1", port, reconnect_backoff=60.0)
+    sender = DurableSender(client, ReplaySpool(tmp_path / "spool"))
+    got = []
+    try:
+        assert sender.send([_enc(0)])
+        server.stop()
+        deadline = time.monotonic() + 5.0
+        while sender.send([_enc(1)]) and time.monotonic() < deadline:
+            time.sleep(0.05)  # until the dead peer surfaces as a send error
+        assert not sender.send([_enc(2)])  # the dial fails: backoff armed
+        server = TCPServer(port=port)
+        server.start()
+        assert not sender.send([_enc(3)])  # inside the backoff: no dial
+        assert sender.stats()["spool_frames"] >= 3
+        assert sender.drain_before_exit(wait_s=5.0)
+        deadline = time.monotonic() + 10.0
+        while {1, 2, 3} - {p["meta"]["seq"] for p in got} and time.monotonic() < deadline:
+            server.wait_for_data(0.1)
+            got.extend(server.drain_decoded())
+        assert {1, 2, 3} <= {p["meta"]["seq"] for p in got}
     finally:
         sender.close()
         client.close()

@@ -31,6 +31,8 @@ import jax
 import jax.numpy as jnp
 import flax.linen as nn
 
+from traceml_tpu.ops.attention import latent_causal_attention, partitioned_step
+
 
 @dataclasses.dataclass(frozen=True)
 class MLAConfig:
@@ -287,8 +289,13 @@ class LatentAttention(nn.Module):
     each head's non-RoPE key and its value through ``kv_b_proj``.  RoPE
     follows the published checkpoint layout: the RoPE parts are
     de-interleaved (even features, then odd) before rotate-half.  Scores
-    are scaled by 1/sqrt(q width); q/k and v widths differ, so the dense
-    causal path computes them."""
+    are scaled by 1/sqrt(q width).  q/k and v widths differ, so attention
+    goes through ``ops.attention.latent_causal_attention``: the fused
+    splash kernels under the ``mla_kernel`` scope from
+    ``_PALLAS_MIN_SEQ`` on, unless the step is partitioned over a mesh
+    (a Pallas call is not partitioned by GSPMD), else the dense causal
+    path.  The multi-head ``Attention`` keeps ``causal_attention``'s
+    dispatch apart from it."""
 
     cfg: ModelConfig
 
@@ -318,9 +325,7 @@ class LatentAttention(nn.Module):
             k = jnp.concatenate(
                 [kv[..., :dn], jnp.broadcast_to(k_pe, (B, S, nh, dr))], axis=-1
             )
-            from traceml_tpu.ops.attention import causal_attention
-
-            out = causal_attention(q, k, kv[..., dn:]).reshape(B, S, nh * dv)
+            out = latent_causal_attention(q, k, kv[..., dn:]).reshape(B, S, nh * dv)
             return dense(H, "o_proj")(out)
 
 
@@ -493,7 +498,8 @@ def init_train_state(
     tokens = jnp.zeros((2, min(16, cfg.max_seq_len)), dtype=jnp.int32)
 
     def init(key):
-        return model.init(key, tokens)["params"]
+        with partitioned_step(mesh):
+            return model.init(key, tokens)["params"]
 
     out_shardings = None
     if mesh is not None:
@@ -526,12 +532,13 @@ def make_train_step(model: DecoderLM, tx, mesh=None) -> Any:
     same with or without them.  With the ``mesh`` the state was initialised on, the new
     state is held to ``param_shardings``, the layout it came in with:
     left to itself the compiler may lay out optimizer leaves otherwise,
-    and the second step would compile again.
+    and the second step would compile again; and latent attention keeps
+    its dense path (``ops.attention.partitioned_step``).
     """
     import optax
 
     def forward(params, apply, tokens):
-        with jax.named_scope("forward"):
+        with jax.named_scope("forward"), partitioned_step(mesh):
             return loss_and_counters(params, apply, tokens)
 
     def train_step(state, tokens):

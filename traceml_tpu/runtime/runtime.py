@@ -43,6 +43,13 @@ from traceml_tpu.utils.self_trace import span
 #: the gap share the next frame.
 LANE_GAP_S = 0.1
 
+#: Longest the exit drain waits for the aggregator to take what failed
+#: sends left in the spool: the client's backoff cap, so a rank that
+#: exits waits no longer than a rank that kept training would have gone
+#: between two dials.  Long enough for the launcher's restart of a dead
+#: aggregator; a healthy link spends nothing here.
+EXIT_REPLAY_WAIT_S = 15.0
+
 
 class TraceMLRuntime:
     def __init__(
@@ -373,6 +380,8 @@ class TraceMLRuntime:
                 self._take_rank_finished() or []
             )
             self.publisher.publish(extra or None, final=True)
+            if not self.publisher.drain_spool(EXIT_REPLAY_WAIT_S):
+                get_error_log().warning("telemetry left in the replay spool at exit")
 
 
 class NoOpRuntime:

@@ -311,6 +311,13 @@ class TelemetryPublisher:
         except Exception:
             return None
 
+    def drain_spool(self, wait_s: float) -> bool:
+        """Exit drain: replay what failed sends left in the spool, waiting
+        up to ``wait_s`` for the aggregator; True when nothing is left."""
+        if self._durable is None:
+            return True
+        return self._durable.drain_before_exit(wait_s)
+
     def close(self) -> None:
         if self._durable is not None:
             self._durable.close()

@@ -377,6 +377,9 @@ class ShmRingClient:
             return False
         return self.send_encoded_body(body)
 
+    def redial_now(self) -> None:
+        """No backoff to drop: every send tries the ring."""
+
     def pending_bytes(self) -> int:
         """Unconsumed bytes in the ring (producer's view; benign-stale)."""
         if self._mm is None:

@@ -362,6 +362,21 @@ class DurableSender:
         self._spool.clear()
         return True
 
+    def drain_before_exit(self, wait_s: float, poll_s: float = 0.25) -> bool:
+        """Replay the spool, dialing without backoff every ``poll_s``,
+        until it is empty or ``wait_s`` has passed; True when empty.  A
+        rank that finishes while its aggregator is being restarted would
+        otherwise exit with its last rows and its ``rank_finished``
+        marker still on disk."""
+        deadline = time.monotonic() + wait_s
+        while True:
+            self._client.redial_now()
+            if self.replay():
+                return True
+            if time.monotonic() >= deadline:
+                return False
+            time.sleep(poll_s)
+
     def _send_group(self, raws: List[bytes], last_seq: int) -> bool:
         body = (
             msgpack_codec.MSGPACK_PREFIX

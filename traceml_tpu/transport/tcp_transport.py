@@ -611,6 +611,13 @@ class TCPClient:
         )
         self._backoff_cur = random.uniform(window / 2.0, window)
 
+    def redial_now(self) -> None:
+        """Drop the current backoff wait: the next send dials at once.
+        For the rank's exit drain, whose last sends must not sit out a
+        window that began while the aggregator was down."""
+        with self._lock:
+            self._backoff_cur = 0.0
+
     def _ensure_connected(self) -> Optional[socket.socket]:
         with self._lock:
             if self._sock is not None:
